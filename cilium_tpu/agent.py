@@ -29,6 +29,10 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from cilium_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     if args.trace_sample_rate is not None:
         from cilium_tpu import tracing
 

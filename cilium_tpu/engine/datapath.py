@@ -162,10 +162,10 @@ class FlowBatch:
         is_fragment=None,
     ) -> "FlowBatch":
         """Pack all eight columns into ONE [8, B] u32 host array and
-        upload it as a single transfer — per-array device_put pays the
-        transport's ~100 ms fixed round-trip latency EIGHT times per
-        batch, which dominated the sustained-churn loop.  A tiny
-        jitted splitter restores the typed columns on device."""
+        upload it as a single transfer instead of eight per-array
+        device_puts, each with its own fixed cost (not measured on
+        the local chip).  A tiny jitted splitter restores the typed
+        columns on device."""
         b = len(ep_index)
         if is_fragment is None:
             is_fragment = np.zeros(b, dtype=bool)
@@ -790,9 +790,7 @@ def _datapath_kernel_accum_pair_telem_packed4_stacked(
 ):
     """Both packed4 half-batches in ONE staged array ([2, 4, B] u32):
     the async staging pipeline pays a single device_put per batch
-    pair — on latency-bound transports the second transfer's round
-    trip is pure overhead — and the direction split happens inside
-    the jit."""
+    pair, and the direction split happens inside the jit."""
     return _datapath_kernel_accum_pair_telem_packed4(
         tables, pair[0], pair[1], acc, telem
     )

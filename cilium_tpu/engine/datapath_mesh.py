@@ -60,6 +60,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cilium_tpu import tracing
@@ -75,7 +76,6 @@ from cilium_tpu.engine.sharded import (
     failover_counts,
     failover_lattice_probes,
     fold_l3_aug,
-    shard_map,
 )
 from cilium_tpu.maps.policymap import INGRESS
 

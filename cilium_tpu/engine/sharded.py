@@ -24,6 +24,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cilium_tpu.compiler.tables import PolicyTables
@@ -34,27 +35,6 @@ from cilium_tpu.engine.verdict import (
     _combine,
     _index,
 )
-
-try:  # jax>=0.4.30 moved shard_map out of experimental
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f=None, **kwargs):
-    """shard_map with the replication-check knob spelled per the
-    installed jax: newer releases renamed check_rep → check_vma, and
-    passing the wrong name is a TypeError at decoration time."""
-    import inspect
-
-    params = inspect.signature(_shard_map).parameters
-    if "check_vma" not in params and "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" not in params and "check_rep" in kwargs:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    if f is None:
-        return partial(_shard_map, **kwargs)
-    return _shard_map(f, **kwargs)
 
 
 def table_specs(batch_axis: str, table_axis: str) -> PolicyTables:

@@ -83,8 +83,7 @@ class TupleBatch:
         is_fragment=None,
     ) -> "TupleBatch":
         """Single-transfer upload: one [6, B] u32 pack instead of six
-        device_puts (each pays the transport's ~100 ms round trip —
-        see FlowBatch.from_numpy)."""
+        device_puts (see FlowBatch.from_numpy)."""
         b = len(ep_index)
         if is_fragment is None:
             is_fragment = np.zeros(b, dtype=bool)

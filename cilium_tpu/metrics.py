@@ -60,6 +60,11 @@ class Counter:
         with self._lock:
             return self._values[label_values]
 
+    def snapshot(self) -> Dict[Tuple[str, ...], float]:
+        """Every label tuple seen so far, with its value."""
+        with self._lock:
+            return dict(self._values)
+
     def expose(self) -> List[str]:
         lines = [
             f"# HELP {self.name} {escape_help(self.help)}",

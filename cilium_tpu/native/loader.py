@@ -10,6 +10,7 @@ missing, and as the differential-testing oracle).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,7 +20,17 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "tupledec.cpp")
-_LIB = os.path.join(_HERE, "_tupledec.so")
+
+
+def _lib_path() -> str:
+    """The shared object built from THIS source: its name carries a
+    hash of tupledec.cpp, so a copied tree or a stale build can never
+    load a library compiled from other source (mtimes do not survive
+    a copy)."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"_tupledec-{digest}.so")
+
 
 # Python-side declaration of struct flow_record (must byte-match C++).
 FLOW_RECORD_DTYPE = np.dtype(
@@ -48,20 +59,23 @@ class NativeUnavailable(RuntimeError):
 
 def _build() -> Optional[ctypes.CDLL]:
     global _build_failed
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(
-        _SRC
-    ):
-        return ctypes.CDLL(_LIB)
+    lib_path = _lib_path()
+    if os.path.exists(lib_path):
+        return ctypes.CDLL(lib_path)
+    # build beside the target and rename into place: concurrent
+    # builders (test workers) never load a half-written object
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             [
                 "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                _SRC, "-o", _LIB,
+                _SRC, "-o", tmp,
             ],
             check=True,
             capture_output=True,
         )
-        return ctypes.CDLL(_LIB)
+        os.replace(tmp, lib_path)
+        return ctypes.CDLL(lib_path)
     except (subprocess.CalledProcessError, FileNotFoundError):
         _build_failed = True
         return None

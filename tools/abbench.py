@@ -7,12 +7,15 @@ method.  Run on the real TPU.
 
 from __future__ import annotations
 
+import os
 import sys
 import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(
+    0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
 def timed(fn, *args, reps=16, outstanding=4):

@@ -1,11 +1,14 @@
 """Per-phase profile of the replay_pool churn loop."""
 
+import os
 import sys
 import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(
+    0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
 def main():

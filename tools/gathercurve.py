@@ -3,12 +3,15 @@ prototype (quarter-select row layout) vs the dense l4_combined gather."""
 
 from __future__ import annotations
 
+import os
 import sys
 import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(
+    0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
 def timed(fn, *args, reps=16, outstanding=4):

@@ -14,12 +14,15 @@ D2H np.asarray on the last output; subtract a floor variant.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(
+    0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
 def timed(fn, tables, flows, acc_factory, reps=8, outstanding=4):
