@@ -952,8 +952,8 @@ def run_config5(args) -> None:
 
     # --- tracing reference loop: the same instrumented batches with
     # span-plane bookkeeping riding each dispatch (a root span per
-    # stream + per-batch dispatch spans with per-chip children — the
-    # daemon's process_flows span shape at this batch cadence).  The
+    # stream + per-batch dispatch spans — the daemon's process_flows
+    # span shape at this batch cadence).  The
     # overhead is the tracer's OWN accounted bookkeeping seconds
     # (Tracer.overhead_s: begin/finish/ring-append time measured
     # inside the tracer) over the pipeline time without it — the same
@@ -976,15 +976,12 @@ def run_config5(args) -> None:
             fin, feg = flow_batches[i % len(flow_batches)]
             with bench_tracer.span(
                 "dispatch", site="bench", attrs={"batch": i}
-            ) as bsp:
+            ):
                 out_i, out_e, acc_tr, telem_tr = (
                     datapath_step_accum_pair_telem(
                         tables, fin, feg, acc_tr, telem_tr
                     )
                 )
-            _tracing.record_chip_spans(
-                bench_tracer, bsp, 1, 2 * half, "bench"
-            )
             outs.append((out_i, out_e))
             if len(outs) > 4:
                 jax.block_until_ready(outs.pop(0))
@@ -1011,7 +1008,7 @@ def run_config5(args) -> None:
         spans_dropped=bench_tracer.dropped,
         note=(
             "span-plane bookkeeping (root + per-batch dispatch "
-            "spans + per-chip children) measured inside the "
+            "spans) measured inside the "
             "instrumented pair pipeline; gate < 3% at the default "
             "sample rate"
         ),

@@ -1543,7 +1543,7 @@ class Daemon:
 
         Span-plane attribution: the device attempt runs under an
         `engine.dispatch` span (error status + breaker events when it
-        fails, per-chip children when it succeeds); the failover fold
+        fails); the failover fold
         runs under `engine.hostpath` — one trace shows which plane
         served the batch and why."""
         from cilium_tpu.engine.hostpath import lattice_fold_host
@@ -1679,10 +1679,6 @@ class Daemon:
                     )
                     dispatched = None
             if dispatched is not None:
-                tracing.record_chip_spans(
-                    self.tracer, sp, 1,
-                    int(batch.ep_index.shape[0]), "engine.dispatch",
-                )
                 # shadow sampling (single-chip path): the SECOND
                 # dispatch rides the already-staged TupleBatch
                 # against the shadow epoch, diffed on device

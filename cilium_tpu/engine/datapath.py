@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from cilium_tpu import tracing
 from cilium_tpu.compiler.tables import PolicyTables
 from cilium_tpu.ct.device import (
     CTSnapshot,
@@ -232,21 +233,22 @@ def pack_flow_records4(
 
 def flow_batch_from_packed4(packed) -> "FlowBatch":
     """Device half of packed4 (traced: call from inside a jit)."""
-    w3 = packed[3]
-    return FlowBatch(
-        ep_index=(w3 >> jnp.uint32(16)).astype(jnp.int32),
-        saddr=packed[0],
-        daddr=packed[1],
-        sport=(packed[2] >> jnp.uint32(16)).astype(jnp.int32),
-        dport=(packed[2] & jnp.uint32(0xFFFF)).astype(jnp.int32),
-        proto=((w3 >> jnp.uint32(8)) & jnp.uint32(0xFF)).astype(
-            jnp.int32
-        ),
-        direction=((w3 >> jnp.uint32(1)) & jnp.uint32(1)).astype(
-            jnp.int32
-        ),
-        is_fragment=(w3 & jnp.uint32(1)).astype(bool),
-    )
+    with jax.named_scope("unpack"):
+        w3 = packed[3]
+        return FlowBatch(
+            ep_index=(w3 >> jnp.uint32(16)).astype(jnp.int32),
+            saddr=packed[0],
+            daddr=packed[1],
+            sport=(packed[2] >> jnp.uint32(16)).astype(jnp.int32),
+            dport=(packed[2] & jnp.uint32(0xFFFF)).astype(jnp.int32),
+            proto=((w3 >> jnp.uint32(8)) & jnp.uint32(0xFF)).astype(
+                jnp.int32
+            ),
+            direction=((w3 >> jnp.uint32(1)) & jnp.uint32(1)).astype(
+                jnp.int32
+            ),
+            is_fragment=(w3 & jnp.uint32(1)).astype(bool),
+        )
 
 
 def flow_batch_from_packed(packed) -> "FlowBatch":
@@ -347,7 +349,12 @@ def _datapath_core(
     `static_direction` compiles a direction-specialized program, the
     analog of bpf_lxc's separate from-container/to-container sections:
     the INGRESS program has no LB/service-CT stages at all (3 fewer
-    gathers), exactly as ingress packets never traverse lb4_local."""
+    gathers), exactly as ingress packets never traverse lb4_local.
+
+    Each stage runs under a `jax.named_scope` (prefilter, ct, lb,
+    ipcache, lattice, verdict, accounting; the packed4 unpack under
+    unpack): metadata only, so a profiler trace names a device op by
+    its stage and the compiled program is otherwise unchanged."""
     if static_direction is None:
         ingress = flows.direction == INGRESS
     else:
@@ -359,7 +366,8 @@ def _datapath_core(
     # deny lists are a broadcast compare — zero gathers ------------------
     from cilium_tpu.prefilter import prefilter_drop
 
-    pre_drop = prefilter_drop(tables.prefilter, flows.saddr)
+    with jax.named_scope("prefilter"):
+        pre_drop = prefilter_drop(tables.prefilter, flows.saddr)
 
     # -- 2+3. ONE CT row gather serves both probes: the bucket row is
     # fetched by the ORIGINAL tuple's normalized hash; the
@@ -369,55 +377,60 @@ def _datapath_core(
     # against the SAME row — DNATed entries are dual-homed there by
     # CTBucketIndex, so the second row gather the reference pays in
     # nanoseconds (and we'd pay ~7 ns/flow for) disappears.
-    ct_rows = ct_fetch_rows(
-        tables.ct, flows.daddr, flows.saddr, flows.dport, flows.sport,
-        flows.proto,
-    )
+    with jax.named_scope("ct"):
+        ct_rows = ct_fetch_rows(
+            tables.ct, flows.daddr, flows.saddr, flows.dport,
+            flows.sport, flows.proto,
+        )
     if static_direction == INGRESS:
-        zero = jnp.zeros(flows.dport.shape, jnp.int32)
-        eff_daddr = flows.daddr.astype(jnp.uint32)
-        eff_dport = flows.dport
-        rev_nat = zero
-        lb_slave = zero
+        with jax.named_scope("lb"):
+            zero = jnp.zeros(flows.dport.shape, jnp.int32)
+            eff_daddr = flows.daddr.astype(jnp.uint32)
+            eff_dport = flows.dport
+            rev_nat = zero
+            lb_slave = zero
     else:
-        svc_dir = jnp.full_like(flows.direction, CT_SERVICE)
-        _, _, svc_slave = ct_probe_rows(
+        with jax.named_scope("ct"):
+            svc_dir = jnp.full_like(flows.direction, CT_SERVICE)
+            _, _, svc_slave = ct_probe_rows(
+                tables.ct,
+                ct_rows,
+                flows.daddr,
+                flows.saddr,
+                flows.dport,
+                flows.sport,
+                flows.proto,
+                svc_dir,
+            )
+        with jax.named_scope("lb"):
+            svc_found, slave, lb_daddr, lb_dport, lb_rev = lb_select_batch(
+                tables.lb,
+                flows.saddr,
+                flows.daddr,
+                flows.sport,
+                flows.dport,
+                flows.proto,
+                ct_slave=svc_slave,
+            )
+            do_lb = (~ingress) & svc_found
+            eff_daddr = jnp.where(
+                do_lb, lb_daddr, flows.daddr.astype(jnp.uint32)
+            )
+            eff_dport = jnp.where(do_lb, lb_dport, flows.dport)
+            rev_nat = jnp.where(do_lb, lb_rev, 0)
+            lb_slave = jnp.where(do_lb, slave, 0)
+
+    with jax.named_scope("ct"):
+        ct_res, ct_rev, _ = ct_probe_rows(
             tables.ct,
             ct_rows,
-            flows.daddr,
+            eff_daddr,
             flows.saddr,
-            flows.dport,
+            eff_dport,
             flows.sport,
             flows.proto,
-            svc_dir,
+            flows.direction,
         )
-        svc_found, slave, lb_daddr, lb_dport, lb_rev = lb_select_batch(
-            tables.lb,
-            flows.saddr,
-            flows.daddr,
-            flows.sport,
-            flows.dport,
-            flows.proto,
-            ct_slave=svc_slave,
-        )
-        do_lb = (~ingress) & svc_found
-        eff_daddr = jnp.where(
-            do_lb, lb_daddr, flows.daddr.astype(jnp.uint32)
-        )
-        eff_dport = jnp.where(do_lb, lb_dport, flows.dport)
-        rev_nat = jnp.where(do_lb, lb_rev, 0)
-        lb_slave = jnp.where(do_lb, slave, 0)
-
-    ct_res, ct_rev, _ = ct_probe_rows(
-        tables.ct,
-        ct_rows,
-        eff_daddr,
-        flows.saddr,
-        eff_dport,
-        flows.sport,
-        flows.proto,
-        flows.direction,
-    )
 
     # -- 4. identity derivation (ipcache LPM; WORLD fallback) ---------------
     from cilium_tpu.ipcache.lpm import (
@@ -426,58 +439,59 @@ def _datapath_core(
         ipcache_lookup_fused,
     )
 
-    sec_ip = jnp.where(
-        ingress, flows.saddr.astype(jnp.uint32), eff_daddr
-    )
-    idx_known = None
-    if (
-        isinstance(tables.ipcache, IPCacheDevice)
-        and tables.ipcache.values_are_idx
-    ):
-        looked, l3_word = ipcache_lookup_fused(
-            tables.ipcache, sec_ip, ingress=ingress
+    with jax.named_scope("ipcache"):
+        sec_ip = jnp.where(
+            ingress, flows.saddr.astype(jnp.uint32), eff_daddr
         )
-        n = tables.policy.id_table.shape[0]
-        miss = looked == 0
-        ipc_miss = miss
-        # UNKNOWN_IDX = ipcache entry whose identity is outside the
-        # policy universe: present (no WORLD fallback) but not-known
-        vp = jnp.where(
-            miss, jnp.uint32(tables.ipcache.world_plus1), looked
-        )
-        known = (vp != 0) & (vp != jnp.uint32(UNKNOWN_IDX))
-        idx = jnp.where(known, vp - 1, jnp.uint32(n - 1)).astype(
-            jnp.int32
-        )
-        if l3_word is not None:
-            # miss → WORLD's l3 bits, selected by direction
-            l3_word = jnp.where(
-                miss,
-                jnp.where(
-                    ingress,
-                    jnp.uint32(tables.ipcache.world_l3_in),
-                    jnp.uint32(tables.ipcache.world_l3_out),
-                ),
-                l3_word,
+        idx_known = None
+        if (
+            isinstance(tables.ipcache, IPCacheDevice)
+            and tables.ipcache.values_are_idx
+        ):
+            looked, l3_word = ipcache_lookup_fused(
+                tables.ipcache, sec_ip, ingress=ingress
             )
-            l3_bit = (
-                (l3_word >> flows.ep_index.astype(jnp.uint32)) & 1
-            ).astype(bool)
-            idx_known = (idx, known, l3_bit)
+            n = tables.policy.id_table.shape[0]
+            miss = looked == 0
+            ipc_miss = miss
+            # UNKNOWN_IDX = ipcache entry whose identity is outside the
+            # policy universe: present (no WORLD fallback) but not-known
+            vp = jnp.where(
+                miss, jnp.uint32(tables.ipcache.world_plus1), looked
+            )
+            known = (vp != 0) & (vp != jnp.uint32(UNKNOWN_IDX))
+            idx = jnp.where(known, vp - 1, jnp.uint32(n - 1)).astype(
+                jnp.int32
+            )
+            if l3_word is not None:
+                # miss → WORLD's l3 bits, selected by direction
+                l3_word = jnp.where(
+                    miss,
+                    jnp.where(
+                        ingress,
+                        jnp.uint32(tables.ipcache.world_l3_in),
+                        jnp.uint32(tables.ipcache.world_l3_out),
+                    ),
+                    l3_word,
+                )
+                l3_bit = (
+                    (l3_word >> flows.ep_index.astype(jnp.uint32)) & 1
+                ).astype(bool)
+                idx_known = (idx, known, l3_bit)
+            else:
+                idx_known = (idx, known)
+            if emit_sec_id:
+                sec_id = tables.policy.id_table[idx]
+            else:
+                sec_id = idx.astype(jnp.uint32)  # sec_idx form
+            lattice_identity = jnp.zeros_like(looked)  # unused
         else:
-            idx_known = (idx, known)
-        if emit_sec_id:
-            sec_id = tables.policy.id_table[idx]
-        else:
-            sec_id = idx.astype(jnp.uint32)  # sec_idx form
-        lattice_identity = jnp.zeros_like(looked)  # unused
-    else:
-        looked = _lookup_kernel(tables.ipcache, sec_ip)
-        ipc_miss = looked == 0
-        sec_id = jnp.where(
-            looked == 0, jnp.uint32(RESERVED_WORLD), looked
-        ).astype(jnp.uint32)
-        lattice_identity = sec_id
+            looked = _lookup_kernel(tables.ipcache, sec_ip)
+            ipc_miss = looked == 0
+            sec_id = jnp.where(
+                looked == 0, jnp.uint32(RESERVED_WORLD), looked
+            ).astype(jnp.uint32)
+            lattice_identity = sec_id
 
     # -- 5. policy lattice (always evaluated, bpf_lxc.c:959) ----------------
     resolved = TupleBatch(
@@ -493,15 +507,16 @@ def _datapath_core(
     # same (probe1, probe2, probe3, proxy, j, idx) contract, so the
     # combine / counter / telemetry stages below are shared code and
     # the bit-identity surface is the probe outputs alone
-    if lattice_fn is None:
-        probe1, probe2, probe3, proxy, j, idx = _probes(
-            tables.policy, resolved, idx_known=idx_known
-        )
-    else:
-        probe1, probe2, probe3, proxy, j, idx = lattice_fn(
-            tables.policy, resolved, idx_known
-        )
-    v = _combine(probe1, probe2, probe3, proxy, resolved.is_fragment)
+    with jax.named_scope("lattice"):
+        if lattice_fn is None:
+            probe1, probe2, probe3, proxy, j, idx = _probes(
+                tables.policy, resolved, idx_known=idx_known
+            )
+        else:
+            probe1, probe2, probe3, proxy, j, idx = lattice_fn(
+                tables.policy, resolved, idx_known
+            )
+        v = _combine(probe1, probe2, probe3, proxy, resolved.is_fragment)
     deferred = None
     if with_counters:
         if defer_counters:
@@ -511,45 +526,47 @@ def _datapath_core(
             # (scatter cost is near size-independent on this chip)
             deferred = (resolved, j, idx)
         else:
-            if acc is None:
-                acc = make_counter_buffers(tables.policy)
-            acc = _accumulate_counters(
-                v, resolved, j, idx, acc,
-                tables.policy.l4_meta.shape[2],
-            )
+            with jax.named_scope("accounting"):
+                if acc is None:
+                    acc = make_counter_buffers(tables.policy)
+                acc = _accumulate_counters(
+                    v, resolved, j, idx, acc,
+                    tables.policy.l4_meta.shape[2],
+                )
 
     # -- 6. combine (bpf_lxc.c:962-985) -------------------------------------
-    pol_allow = v.allowed.astype(bool)
-    pass_ct = (ct_res == CT_REPLY) | (ct_res == CT_RELATED)
-    allowed = (~pre_drop) & (pass_ct | pol_allow)
-    ct_delete = (
-        (ct_res == CT_ESTABLISHED) & ~pol_allow & ~pass_ct & ~pre_drop
-    )
-    ct_create = (ct_res == CT_NEW) & allowed
-    proxy = jnp.where(
-        pol_allow
-        & ((ct_res == CT_NEW) | (ct_res == CT_ESTABLISHED))
-        & allowed,
-        v.proxy_port,
-        0,
-    )
-
-    # -- 7. overlay forwarding decision (encap_and_redirect,
-    # bpf/lib/encap.h:26 via bpf_lxc's ipv4 tail): an ALLOWED egress
-    # flow whose destination falls in a remote node's pod CIDR gets
-    # the tunnel endpoint (the identity to carry rides in sec_id,
-    # exactly as the reference stuffs seclabel into the tunnel key);
-    # 0 = direct route / local delivery ---------------------------------
-    if tables.tunnel is not None and static_direction != INGRESS:
-        from cilium_tpu.tunnel import tunnel_select
-
-        tunnel_ep = jnp.where(
-            allowed & ~ingress,
-            tunnel_select(tables.tunnel, eff_daddr),
-            jnp.uint32(0),
+    with jax.named_scope("verdict"):
+        pol_allow = v.allowed.astype(bool)
+        pass_ct = (ct_res == CT_REPLY) | (ct_res == CT_RELATED)
+        allowed = (~pre_drop) & (pass_ct | pol_allow)
+        ct_delete = (
+            (ct_res == CT_ESTABLISHED) & ~pol_allow & ~pass_ct & ~pre_drop
         )
-    else:
-        tunnel_ep = jnp.zeros(eff_daddr.shape, jnp.uint32)
+        ct_create = (ct_res == CT_NEW) & allowed
+        proxy = jnp.where(
+            pol_allow
+            & ((ct_res == CT_NEW) | (ct_res == CT_ESTABLISHED))
+            & allowed,
+            v.proxy_port,
+            0,
+        )
+
+        # -- 7. overlay forwarding decision (encap_and_redirect,
+        # bpf/lib/encap.h:26 via bpf_lxc's ipv4 tail): an ALLOWED egress
+        # flow whose destination falls in a remote node's pod CIDR gets
+        # the tunnel endpoint (the identity to carry rides in sec_id,
+        # exactly as the reference stuffs seclabel into the tunnel key);
+        # 0 = direct route / local delivery ---------------------------------
+        if tables.tunnel is not None and static_direction != INGRESS:
+            from cilium_tpu.tunnel import tunnel_select
+
+            tunnel_ep = jnp.where(
+                allowed & ~ingress,
+                tunnel_select(tables.tunnel, eff_daddr),
+                jnp.uint32(0),
+            )
+        else:
+            tunnel_ep = jnp.zeros(eff_daddr.shape, jnp.uint32)
 
     out = DatapathVerdicts(
         allowed=allowed.astype(jnp.uint8),
@@ -577,22 +594,23 @@ def _datapath_core(
         # per-tuple D2H)
         from cilium_tpu.engine.verdict import telemetry_masks
 
-        masks = telemetry_masks(
-            pre_drop, ct_res, v.match_kind, allowed, ct_delete,
-            proxy, lb_slave, ipc_miss,
-        )
-        # one reduction pair per column: the egress row is the
-        # column total minus the ingress row (direction partitions
-        # the batch), so 2T sums become T+T-with-const-folding —
-        # and in the direction-specialized programs `ingress` is a
-        # constant, so XLA folds one of the two rows to zeros
-        row_in = jnp.stack(
-            [jnp.sum(m & ingress, dtype=jnp.uint32) for m in masks]
-        )
-        col_total = jnp.stack(
-            [jnp.sum(m, dtype=jnp.uint32) for m in masks]
-        )
-        trow = jnp.stack([row_in, col_total - row_in])
+        with jax.named_scope("accounting"):
+            masks = telemetry_masks(
+                pre_drop, ct_res, v.match_kind, allowed, ct_delete,
+                proxy, lb_slave, ipc_miss,
+            )
+            # one reduction pair per column: the egress row is the
+            # column total minus the ingress row (direction partitions
+            # the batch), so 2T sums become T+T-with-const-folding —
+            # and in the direction-specialized programs `ingress` is a
+            # constant, so XLA folds one of the two rows to zeros
+            row_in = jnp.stack(
+                [jnp.sum(m & ingress, dtype=jnp.uint32) for m in masks]
+            )
+            col_total = jnp.stack(
+                [jnp.sum(m, dtype=jnp.uint32) for m in masks]
+            )
+            trow = jnp.stack([row_in, col_total - row_in])
     if with_counters:
         if defer_counters:
             tail = (v, *deferred)
@@ -682,14 +700,15 @@ def _datapath_kernel_accum_pair(tables, flows_in, flows_eg, acc):
         tables, flows_eg, with_counters=True, emit_sec_id=False,
         static_direction=EGRESS, defer_counters=True,
     )
-    kg = tables.policy.l4_meta.shape[2]
-    ep_i, d_i, c_i, w_i = _counter_cols(v_i, res_i, j_i, idx_i, kg)
-    ep_e, d_e, c_e, w_e = _counter_cols(v_e, res_e, j_e, idx_e, kg)
-    acc = acc.at[
-        jnp.concatenate([ep_i, ep_e]),
-        jnp.concatenate([d_i, d_e]),
-        jnp.concatenate([c_i, c_e]),
-    ].add(jnp.concatenate([w_i, w_e]))
+    with jax.named_scope("accounting"):
+        kg = tables.policy.l4_meta.shape[2]
+        ep_i, d_i, c_i, w_i = _counter_cols(v_i, res_i, j_i, idx_i, kg)
+        ep_e, d_e, c_e, w_e = _counter_cols(v_e, res_e, j_e, idx_e, kg)
+        acc = acc.at[
+            jnp.concatenate([ep_i, ep_e]),
+            jnp.concatenate([d_i, d_e]),
+            jnp.concatenate([c_i, c_e]),
+        ].add(jnp.concatenate([w_i, w_e]))
     return out_i, out_e, acc
 
 
@@ -743,15 +762,17 @@ def _datapath_kernel_accum_pair_telem(
         static_direction=EGRESS, defer_counters=True,
         collect_telemetry=True,
     )
-    kg = tables.policy.l4_meta.shape[2]
-    ep_i, d_i, c_i, w_i = _counter_cols(v_i, res_i, j_i, idx_i, kg)
-    ep_e, d_e, c_e, w_e = _counter_cols(v_e, res_e, j_e, idx_e, kg)
-    acc = acc.at[
-        jnp.concatenate([ep_i, ep_e]),
-        jnp.concatenate([d_i, d_e]),
-        jnp.concatenate([c_i, c_e]),
-    ].add(jnp.concatenate([w_i, w_e]))
-    return out_i, out_e, acc, telem + trow_i + trow_e
+    with jax.named_scope("accounting"):
+        kg = tables.policy.l4_meta.shape[2]
+        ep_i, d_i, c_i, w_i = _counter_cols(v_i, res_i, j_i, idx_i, kg)
+        ep_e, d_e, c_e, w_e = _counter_cols(v_e, res_e, j_e, idx_e, kg)
+        acc = acc.at[
+            jnp.concatenate([ep_i, ep_e]),
+            jnp.concatenate([d_i, d_e]),
+            jnp.concatenate([c_i, c_e]),
+        ].add(jnp.concatenate([w_i, w_e]))
+        telem = telem + trow_i + trow_e
+    return out_i, out_e, acc, telem
 
 
 datapath_step_telem = jax.jit(_datapath_kernel_telem)
@@ -936,9 +957,11 @@ def persistent_pair_program(k_pairs: int):
     def program(tables, pairs, acc, telem):
         def step(carry, pair):
             acc, telem = carry
+            with jax.named_scope("unpack"):
+                packed_in, packed_eg = pair[0], pair[1]
             out_i, out_e, acc, telem = (
                 _datapath_kernel_accum_pair_telem_packed4(
-                    tables, pair[0], pair[1], acc, telem
+                    tables, packed_in, packed_eg, acc, telem
                 )
             )
             return (acc, telem), (out_i, out_e)
@@ -967,14 +990,21 @@ class PersistentPairDispatcher:
 
     The jit-tracking proof rides `site`: wrap-tracked launches land
     in cilium_jit_cache_*{site} so a test (or the bench) can assert
-    K pairs cost exactly one executable call."""
+    K pairs cost exactly one executable call.
+
+    Each launch runs under a `datapath.launch` span (attrs pairs,
+    tuples, bytes) with four children in order: `datapath.stack`
+    (the host np.stack), `datapath.upload` (device_put),
+    `datapath.enqueue` (the program call) and `datapath.outputs` (the
+    per-pair slices).  Nothing waits on the device: each span times
+    the host call, so an asynchronous upload shows as a short upload
+    span."""
 
     def __init__(
         self, tables, k_pairs: int, acc, telem,
         site: str = "datapath.persistent",
     ) -> None:
-        from cilium_tpu import tracing
-
+        self.site = site
         self.tables = tables
         self.k = max(int(k_pairs), 1)
         self.acc = acc
@@ -993,29 +1023,39 @@ class PersistentPairDispatcher:
         """Stage one [2, 4, B] host pair; when the K-th arrives the
         super-batch launches (one dispatch for all K).  Returns the
         drained per-pair (out_i, out_e) tuples, [] while staging."""
+        tracer = tracing.tracer
         self._staged.append(pair_host)
         if len(self._staged) < self.k:
             return []
-        staged_n = len(self._staged)
-        stacked = jax.device_put(
-            np.stack(self._staged)
-        )
-        self._staged = []
-        outs_i, outs_e, self.acc, self.telem = self._program(
-            self.tables, stacked, self.acc, self.telem
-        )
-        self.launches += 1
-        # persistent-program launch accounting for the perf plane:
-        # pairs/launches = realized staging depth at scrape time
-        metrics.datapath_persistent_launches.inc()
-        metrics.datapath_persistent_pairs.inc(value=staged_n)
-        return [
-            (
-                jax.tree.map(lambda a: a[i], outs_i),
-                jax.tree.map(lambda a: a[i], outs_e),
-            )
-            for i in range(self.k)
-        ]
+        staged, self._staged = self._staged, []
+        attrs = {
+            "pairs": len(staged),
+            "tuples": sum(p.shape[0] * p.shape[-1] for p in staged),
+            "bytes": sum(p.nbytes for p in staged),
+        }
+        with tracer.span("datapath.launch", site=self.site, attrs=attrs):
+            with tracer.span("datapath.stack", site=self.site):
+                host = np.stack(staged)
+            with tracer.span("datapath.upload", site=self.site):
+                stacked = jax.device_put(host)
+            with tracer.span("datapath.enqueue", site=self.site):
+                outs_i, outs_e, self.acc, self.telem = self._program(
+                    self.tables, stacked, self.acc, self.telem
+                )
+            self.launches += 1
+            # persistent-program launch accounting for the perf plane:
+            # pairs/launches = realized staging depth at scrape time
+            metrics.datapath_persistent_launches.inc()
+            metrics.datapath_persistent_pairs.inc(value=len(staged))
+            with tracer.span("datapath.outputs", site=self.site):
+                outs = [
+                    (
+                        jax.tree.map(lambda a: a[i], outs_i),
+                        jax.tree.map(lambda a: a[i], outs_e),
+                    )
+                    for i in range(self.k)
+                ]
+        return outs
 
     def flush(self):
         """Drain the staged remainder through the per-pair program

@@ -1711,14 +1711,10 @@ def make_async_mesh_dispatcher(
 
 def traced_dispatch(step, mesh, site: str = "engine.sharded"):
     """Wrap a mesh evaluator with span-plane dispatch attribution:
-    each call opens a `mesh.dispatch` span (blocking on the result so
-    the span covers the device execution, not just the enqueue) and
-    synthesizes per-chip `chip.dispatch` children — the SPMD program
-    runs in lockstep, so the parent's window partitions evenly across
-    chips and the children sum to the batch span.  Per-chip spans are
-    what the ROADMAP's per-chip failover item needs to debug: which
-    ordinal's dispatch latency is the outlier.  The wrapped step also
-    counts jit cache hits/misses per call (site label `site`)."""
+    each call opens a `mesh.dispatch` span with the chip and row
+    counts (blocking on the result so the span covers the device
+    execution, not just the enqueue).  The wrapped step also counts
+    jit cache hits/misses per call (site label `site`)."""
     from cilium_tpu import tracing
 
     n_chips = int(mesh.devices.size)
@@ -1729,12 +1725,9 @@ def traced_dispatch(step, mesh, site: str = "engine.sharded"):
         with tracing.tracer.span(
             "mesh.dispatch", site=site,
             attrs={"chips": n_chips, "rows": rows},
-        ) as sp:
+        ):
             out = tracked(tables, batch, *rest)
             jax.block_until_ready(out)
-        tracing.record_chip_spans(
-            tracing.tracer, sp, n_chips, rows, site
-        )
         return out
 
     dispatch.__wrapped__ = step

@@ -9,11 +9,18 @@ ways:
   * in-process via a contextvar — a span opened anywhere under an
     active span becomes its child, so the REST handler's root span
     automatically parents `Daemon.process_flows`, which parents each
-    batch's dispatch, which parents the per-chip children;
+    batch's dispatch, which parents the device dispatch;
   * across processes via a W3C `traceparent`-style HTTP header
     (`00-<trace_id>-<span_id>-<flags>`) accepted and emitted by
     api/server — a client that stamps its own header sees its ids on
     every span, flow record and reply.
+
+Every span begun and finished (not `record`ed) also opens a
+`jax.profiler.TraceAnnotation` under its name, carrying `trace_id` and
+`span_id` as stats when it is sampled: a profiler trace then shows the
+host's spans on the device trace's clock, and a ring span joins its
+trace event by id.  With no profiler running the annotation costs
+well under a microsecond.
 
 Determinism and cost are first-class: ids come from a seedable RNG
 (tests pin exact ids), sampling is HEAD-based (the decision is made
@@ -32,6 +39,7 @@ sampled by the same instrumented sites, so one id connects
 from __future__ import annotations
 
 import contextvars
+import functools
 import random
 import threading
 import time
@@ -140,6 +148,28 @@ def parse_traceparent(value: Optional[str]) -> Optional[SpanContext]:
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    # imported on first use: the CLI and API client import this module
+    # without JAX
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+def _annotate(name: str, span):
+    """Open the profiler annotation of a span (ids only when
+    sampled)."""
+    annotation = _trace_annotation()
+    ann = (
+        annotation(name)
+        if span is _NOOP
+        else annotation(name, trace_id=span.trace_id, span_id=span.span_id)
+    )
+    ann.__enter__()
+    return ann
+
+
 def format_traceparent(span) -> str:
     flags = "01"
     return f"00-{span.trace_id}-{span.span_id}-{flags}"
@@ -215,26 +245,25 @@ class Tracer:
         attrs: Optional[dict] = None,
     ):
         """Open a span and install it as the current context.  Returns
-        (span, token); pair with finish().  `parent` overrides the
-        contextvar chain (the HTTP header case).  An unsampled root
-        yields the noop span — children of a noop stay noop."""
+        (span, token); pair with finish(), on any thread.  `parent`
+        overrides the contextvar chain (the HTTP header case).  An
+        unsampled root yields the noop span — children of a noop stay
+        noop.  Sampled or not, the span's profiler annotation opens
+        here and closes in finish()."""
         t0 = time.perf_counter()
         cur = _current.get()
         if parent is not None:
-            if not parent.sampled:
-                token = _current.set(_NOOP)
-                return _NOOP, token
+            sampled = parent.sampled
             trace_id, parent_id = parent.trace_id, parent.span_id
         elif cur is not None:
-            if cur is _NOOP:
-                token = _current.set(_NOOP)
-                return _NOOP, token
+            sampled = cur is not _NOOP
             trace_id, parent_id = cur.trace_id, cur.span_id
         else:
-            if not self._sampled():
-                token = _current.set(_NOOP)
-                return _NOOP, token
-            trace_id, parent_id = self._gen_ids(128), ""
+            sampled = self._sampled()
+            trace_id = self._gen_ids(128) if sampled else ""
+            parent_id = ""
+        if not sampled:
+            return _NOOP, (_current.set(_NOOP), _annotate(name, _NOOP))
         span = Span(
             trace_id=trace_id,
             span_id=self._gen_ids(64),
@@ -246,18 +275,22 @@ class Tracer:
             attrs=dict(attrs) if attrs else {},
         )
         self.started_total += 1
-        token = _current.set(span)
+        token = (_current.set(span), _annotate(name, span))
         self.overhead_s += time.perf_counter() - t0
         return span, token
 
     def finish(self, span, token, status: Optional[str] = None) -> None:
-        """Close a span, restore the outer context, export to the
-        ring.  Noop spans only restore the context."""
+        """Close a span and its profiler annotation, restore the outer
+        context, export to the ring.  Noop spans only close the
+        annotation and restore the context."""
         t0 = time.perf_counter()
+        ctx_token, ann = token
+        ann.__exit__(None, None, None)
         try:
-            _current.reset(token)
+            _current.reset(ctx_token)
         except ValueError:
-            # token from another context (exotic caller); best-effort
+            # token from another context (a span finished on another
+            # thread); best-effort
             _current.set(None)
         if span is _NOOP:
             return
@@ -292,8 +325,9 @@ class Tracer:
         status: str = "ok",
         ts: Optional[float] = None,
     ):
-        """Export an already-measured span (jit compiles, synthesized
-        per-chip children): no contextvar involvement.  `parent` is a
+        """Export an already-measured span (jit compiles): no
+        contextvar involvement and no profiler annotation (it has no
+        live interval).  `parent` is a
         Span (defaults to the current one).  Recording under an
         UNSAMPLED context is skipped — the head decision made at the
         root covers everything beneath it, so a sampled-out request
@@ -458,31 +492,6 @@ def add_event(name: str, **attrs) -> None:
             **attrs,
         }
     )
-
-
-def record_chip_spans(
-    trc: Tracer, parent, n_chips: int, rows: int, site: str
-) -> None:
-    """Synthesize per-chip dispatch children under a finished parent
-    span: the device step is SPMD — every chip executes the same
-    program over its batch shard in lockstep — so the parent's window
-    partitions evenly across chips (the children sum to the parent,
-    the smoke's tree-integrity invariant)."""
-    if parent is None or parent is _NOOP or not parent.trace_id:
-        return
-    n_chips = max(1, int(n_chips))
-    share = parent.duration / n_chips
-    per_chip = rows // n_chips if n_chips else rows
-    for chip in range(n_chips):
-        trc.record(
-            "chip.dispatch",
-            site=site,
-            duration=share,
-            parent=parent,
-            attrs={"chip": chip, "rows": per_chip},
-            status=parent.status,
-            ts=parent.ts,
-        )
 
 
 class StatSpan:

@@ -100,8 +100,8 @@ def test_mesh_counters_match_single_device(dp, tp):
 def test_traced_dispatch_per_chip_spans():
     """engine.sharded.traced_dispatch: verdicts pass through
     untouched, jit cache hits/misses are counted per call, and each
-    dispatch lands a mesh.dispatch span whose per-chip children
-    partition it — one child per mesh device, rows split evenly."""
+    dispatch lands a mesh.dispatch span carrying the mesh's chip count
+    and the batch's rows."""
     from cilium_tpu import tracing
     from cilium_tpu.engine.sharded import traced_dispatch
     from cilium_tpu.metrics import registry as metrics
@@ -142,19 +142,6 @@ def test_traced_dispatch_per_chip_spans():
     for parent in parents:
         assert parent.attrs["chips"] == 8
         assert parent.attrs["rows"] == len(t["identity"])
-        chips = [
-            s
-            for s in tracer.snapshot()
-            if s.name == "chip.dispatch"
-            and s.parent_id == parent.span_id
-        ]
-        assert [c.attrs["chip"] for c in chips] == list(range(8))
-        assert all(
-            c.attrs["rows"] == len(t["identity"]) // 8
-            for c in chips
-        )
-        total = sum(c.duration for c in chips)
-        assert total == pytest.approx(parent.duration, rel=1e-6)
 
 
 def test_multiword_per_shard_universe():

@@ -1,6 +1,6 @@
 """tools/trace_smoke.py as a tier-1 test: a traced batch end-to-end
 over REST — span tree integrity (every parent exists, the root is
-the REST request, per-chip spans sum to the dispatch span), the
+the REST request, one device dispatch per batch span), the
 flow↔trace join, /debug/profile agreement, failover attribution and
 the tracing-overhead gate (fast, not slow)."""
 
@@ -15,7 +15,7 @@ def test_trace_smoke_tool(capsys):
     got = json.loads(out)
     assert got["smoke"] == "ok"
     assert got["spans"] > 0
-    assert got["chip_spans"] >= got["batch_spans"] >= 1
+    assert got["batch_spans"] >= 1
     assert got["flow_records_joined"] == 512
     assert got["hostpath_spans"] >= 1
     assert got["tracing_overhead_pct"] < 3.0

@@ -105,7 +105,6 @@ def test_head_sampling_inherited_by_children():
         # record() under an unsampled context must not leak spans
         # either (the head decision covers jit.compile etc.)
         assert t.record("jit.compile", "x", 0.1) is None
-        tracing.record_chip_spans(t, root, 2, 64, "x")
     assert t.snapshot() == []
     # rate back to 1: spans record again
     t.sample_rate = 1.0
@@ -142,18 +141,84 @@ def test_query_and_slowest():
     assert {s.trace_id for s in t.get_trace(tid)} == {tid}
 
 
-def test_record_and_chip_spans_partition_parent():
-    t = Tracer(seed=6)
-    with t.span("dispatch") as sp:
-        time.sleep(0.001)
-    tracing.record_chip_spans(t, sp, 4, 1024, "engine.sharded")
-    chips = [s for s in t.snapshot() if s.name == "chip.dispatch"]
-    assert len(chips) == 4
-    assert [c.attrs["chip"] for c in chips] == [0, 1, 2, 3]
-    assert all(c.parent_id == sp.span_id for c in chips)
-    assert all(c.attrs["rows"] == 256 for c in chips)
-    total = sum(c.duration for c in chips)
-    assert total == pytest.approx(sp.duration, rel=1e-6)
+def _profiled(tmp_path, body):
+    """Run `body` under the JAX profiler; {event name: [stats dict]}
+    of every host event in the recorded trace."""
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(dict(ev.stats))
+    return events
+
+
+def test_spans_mirror_into_profiler_trace(tmp_path):
+    """A begun-and-finished span opens a profiler annotation under its
+    name: sampled spans carry their ids as stats (the ring span and
+    the trace event join by id), unsampled ones appear with none, and
+    record() adds no event."""
+    t = Tracer(seed=41)
+    quiet = Tracer(seed=42, sample_rate=0.0)
+    spans = {}
+
+    def body():
+        with t.span("mirror.sampled") as sp:
+            spans["sampled"] = sp
+        with quiet.span("mirror.unsampled"):
+            pass
+        t.record("mirror.recorded", "x", 0.001)
+
+    events = _profiled(tmp_path, body)
+    sp = spans["sampled"]
+    assert events["mirror.sampled"] == [
+        {"trace_id": sp.trace_id, "span_id": sp.span_id}
+    ]
+    assert events["mirror.unsampled"] == [{}]
+    assert "mirror.recorded" not in events
+    assert quiet.snapshot() == []
+
+
+def test_span_finished_on_another_thread(tmp_path):
+    """A span begun on one thread and finished on another closes its
+    annotation and exports without error, profiler on or off."""
+    t = Tracer(seed=43)
+    errors = []
+
+    def cross():
+        span, token = t.begin("cross.thread")
+
+        def finish():
+            try:
+                t.finish(span, token)
+            except Exception as exc:  # noqa: BLE001 — surfaced below
+                errors.append(exc)
+
+        worker = threading.Thread(target=finish)
+        worker.start()
+        worker.join(timeout=10)
+        assert not worker.is_alive()
+        tracing._current.set(None)
+
+    tok = tracing._current.set(None)
+    try:
+        cross()
+        events = _profiled(tmp_path, cross)
+    finally:
+        tracing._current.reset(tok)
+    assert errors == []
+    names = [s.name for s in t.snapshot()]
+    assert names == ["cross.thread", "cross.thread"]
+    assert len(events["cross.thread"]) == 1
+    assert events["cross.thread"][0]["span_id"] == t.snapshot()[1].span_id
 
 
 def test_add_event_lands_on_active_span():
@@ -372,7 +437,7 @@ def test_rest_traceparent_propagation_and_traces_route(tmp_path):
         assert roots[0]["parent_id"] == psid
         assert {s["name"] for s in spans} >= {
             "daemon.process_flows", "host_pack", "dispatch",
-            "engine.dispatch", "chip.dispatch",
+            "engine.dispatch",
         }
 
         # min-ms / site / slowest filters
@@ -443,7 +508,6 @@ def test_trace_cli_renderings(capsys):
     assert cli_main(["trace", tid], api=api) == 0
     tree = capsys.readouterr().out
     assert "daemon.process_flows (daemon)" in tree
-    assert "chip.dispatch" in tree
     # unknown trace id → exit 1, no trace id at all → usage error
     assert cli_main(["trace", "f" * 32], api=api) == 1
     assert cli_main(["trace"], api=api) == 2

@@ -8,7 +8,7 @@ a unix socket, POSTs a flow-record buffer with a caller-supplied
     exists in the trace (the only span whose parent lives outside the
     ring is the root, which parents to OUR injected client span id),
     and the root is the REST request (`http.request` on api.server);
-  * per-chip dispatch spans sum ≈ their device-dispatch parent, and
+  * each per-batch dispatch span holds one device dispatch, and the
     per-batch dispatch spans fit inside the `daemon.process_flows`
     span;
   * the batch's captured FlowRecords carry the SAME trace id
@@ -160,24 +160,14 @@ def assert_durations(spans, root):
         sum(b["duration_ms"] for b in batch_spans)
         <= proc["duration_ms"] * 1.001
     )
-    # per-chip children partition their device-dispatch parent
-    n_chip_spans = 0
+    # each batch span holds exactly one device dispatch
     for b in batch_spans:
         dev = children_of(spans, b["span_id"], "engine.dispatch")
         assert len(dev) == 1, (b, dev)
-        chips = children_of(
-            spans, dev[0]["span_id"], "chip.dispatch"
-        )
-        assert chips, "no per-chip dispatch children"
-        n_chip_spans += len(chips)
-        total = sum(c["duration_ms"] for c in chips)
-        assert abs(total - dev[0]["duration_ms"]) <= max(
-            0.01 * dev[0]["duration_ms"], 1e-3
-        ), (total, dev[0]["duration_ms"])
     # phase spans exist under the process span
     for phase in ("host_pack", "event_fold", "flow_capture"):
         assert children_of(spans, proc["span_id"], phase), phase
-    return proc, batch_spans, n_chip_spans
+    return proc, batch_spans
 
 
 def main() -> int:
@@ -218,9 +208,7 @@ def main() -> int:
         spans = got["spans"]
         assert got["matched"] == len(spans) > 0
         root = assert_tree(spans)
-        proc, batch_spans, n_chip_spans = assert_durations(
-            spans, root
-        )
+        proc, batch_spans = assert_durations(spans, root)
 
         # --- flow records join on the same trace id -------------------
         flows = client.flows_get({"trace-id": CLIENT_TRACE_ID})
@@ -295,7 +283,6 @@ def main() -> int:
                     "smoke": "ok",
                     "spans": len(spans),
                     "batch_spans": len(batch_spans),
-                    "chip_spans": n_chip_spans,
                     "flow_records_joined": flows["matched"],
                     "hostpath_spans": len(hostpath),
                     "tracing_overhead_pct": round(overhead_pct, 4),
