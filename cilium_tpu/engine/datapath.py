@@ -976,10 +976,19 @@ def persistent_pair_program(k_pairs: int):
     return fn
 
 
+@jax.jit
+def stack_pairs(*pairs):
+    """The [K, 2, 4, B] super-batch from K uploaded [2, 4, B] pairs,
+    built on the device (a host np.stack of the same pairs is a copy
+    of the whole launch that the device waits for)."""
+    return jnp.stack(pairs)
+
+
 class PersistentPairDispatcher:
     """Host driver of the persistent program: stages up to `k_pairs`
-    packed4 pair batches, ships them as ONE [K, 2, 4, B] device_put
-    and ONE launch, and keeps the counter/telemetry carry
+    packed4 pair batches, uploads them in ONE device_put, stacks them
+    into the [K, 2, 4, B] super-batch on the device (`stack_pairs`),
+    runs ONE launch, and keeps the counter/telemetry carry
     device-resident across launches (donated) — zero per-pair
     dispatch, zero per-pair host sync.  `submit(pair)` returns a
     list of drained (out_i, out_e) results (empty until a super-batch
@@ -990,15 +999,16 @@ class PersistentPairDispatcher:
 
     The jit-tracking proof rides `site`: wrap-tracked launches land
     in cilium_jit_cache_*{site} so a test (or the bench) can assert
-    K pairs cost exactly one executable call.
+    K pairs cost exactly one executable call; the device stack is
+    tracked at `site + ".stack"`.
 
     Each launch runs under a `datapath.launch` span (attrs pairs,
-    tuples, bytes) with four children in order: `datapath.stack`
-    (the host np.stack), `datapath.upload` (device_put),
-    `datapath.enqueue` (the program call) and `datapath.outputs` (the
-    per-pair slices).  Nothing waits on the device: each span times
-    the host call, so an asynchronous upload shows as a short upload
-    span."""
+    tuples, bytes) with four children in order: `datapath.upload`
+    (one device_put of the K host pairs), `datapath.stack` (the
+    dispatch of the device stack), `datapath.enqueue` (the program
+    call) and `datapath.outputs` (the per-pair slices).  Nothing waits
+    on the device: each span times the host call, so an asynchronous
+    upload shows as a short upload span."""
 
     def __init__(
         self, tables, k_pairs: int, acc, telem,
@@ -1013,6 +1023,7 @@ class PersistentPairDispatcher:
         self._program = tracing.track_jit(
             persistent_pair_program(self.k), site
         )
+        self._stack = tracing.track_jit(stack_pairs, site + ".stack")
         self._pair_fallback = tracing.track_jit(
             datapath_step_accum_pair_telem_packed4_stacked,
             site + ".remainder",
@@ -1034,10 +1045,10 @@ class PersistentPairDispatcher:
             "bytes": sum(p.nbytes for p in staged),
         }
         with tracer.span("datapath.launch", site=self.site, attrs=attrs):
-            with tracer.span("datapath.stack", site=self.site):
-                host = np.stack(staged)
             with tracer.span("datapath.upload", site=self.site):
-                stacked = jax.device_put(host)
+                uploaded = jax.device_put(staged)
+            with tracer.span("datapath.stack", site=self.site):
+                stacked = self._stack(*uploaded)
             with tracer.span("datapath.enqueue", site=self.site):
                 outs_i, outs_e, self.acc, self.telem = self._program(
                     self.tables, stacked, self.acc, self.telem
