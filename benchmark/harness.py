@@ -3,12 +3,15 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name: the cell in BENCHMARK.json, its
-configuration file, its traffic file benchmark/traffic/<traffic>.json
+configuration file (whose optional `world` key names the module that
+builds its world, benchmark/worlds/<world>.py, and benchmark/world.py
+without it), its traffic file benchmark/traffic/<traffic>.json
 (parameters of the loop that its `loop` key names,
 benchmark/loops/<loop>.py) and one reader per per-layer metric,
 benchmark/layer_metrics/<metric>.py.  Adding a cell, a traffic mix, a
-kind of loop or a metric adds files and entries; no file here
-changes.
+kind of world, a kind of loop or a metric adds files and entries; no
+file here changes.  benchmark/world.py states what a world gives and
+who reads it.
 
 Measurement refuses any platform but a TPU: no result line, exit code
 3.  The last line of stdout is the result object; each number that
@@ -32,6 +35,9 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "benchmark")
 TRACE_DIR = os.path.join(HERE, "_out", "trace")
+# where load_module finds each kind of module
+DIRS = {kind: os.path.join(HERE, kind)
+        for kind in ("loops", "layer_metrics", "worlds")}
 
 
 class NoAccelerator(RuntimeError):
@@ -71,8 +77,9 @@ def cell_metrics(spec: dict, name: str):
 
 def load_module(kind: str, name: str):
     """benchmark/<kind>/<name>.py: a traffic file's loop
-    (kind "loops") or a per-layer metric's reader ("layer_metrics")."""
-    path = os.path.join(HERE, kind, name + ".py")
+    (kind "loops"), a per-layer metric's reader ("layer_metrics") or a
+    configuration's world ("worlds")."""
+    path = os.path.join(DIRS[kind], name + ".py")
     if not os.path.isfile(path):
         raise SystemExit(f"no {kind} module {name!r}: {path} is missing")
     mod_spec = importlib.util.spec_from_file_location(
@@ -147,14 +154,24 @@ def say(msg: str) -> None:
 # ---------------------------------------------------------------------------
 
 
-def build_world(cfg: dict):
+def world_module(cfg: dict):
+    """The module that builds configuration `cfg`'s world:
+    benchmark/worlds/<world>.py where `cfg` has a `world` key, else
+    benchmark/world.py."""
+    if "world" in cfg:
+        return load_module("worlds", cfg["world"])
     from benchmark import world as W
 
-    world = W.build_world(cfg, np.random.default_rng(int(cfg["world_seed"])))
+    return W
+
+
+def build_world(cfg: dict):
+    world = world_module(cfg).build_world(
+        cfg, np.random.default_rng(int(cfg["world_seed"]))
+    )
+    sizes = " ".join(f"{k}={v}" for k, v in cfg.items() if type(v) is int)
     say(
-        f"world {cfg['name']}: rules={cfg['rules']} endpoints="
-        f"{cfg['endpoints']} identities={cfg['identities']} pool="
-        f"{cfg['pool']} phases="
+        f"world {cfg['name']}: {sizes} phases="
         + json.dumps({k: round(v, 3) for k, v in world.timings.items()})
     )
     return world
@@ -176,6 +193,7 @@ def run_cell(spec, wl, cfg, traffic, seed, seconds, trace, devs, t_start,
     window_s = float(traffic["trace_seconds"]) if trace else float(seconds)
     phases = {"start_to_world_s": time.perf_counter() - t_start}
     t0 = time.perf_counter()
+    world.cfg = cfg
     loop = loop_mod.build(world, traffic, seed, say)
     phases["traffic_s"] = time.perf_counter() - t0
     t0 = time.perf_counter()

@@ -1,5 +1,6 @@
 """Mean duration, in ms, of the traced window's `datapath.stack` spans:
-the host's np.stack of a launch's pairs.  Recorded by
+the host's dispatch of the device stack of a launch's pairs (XLA
+module `jit_stack_pairs`), after their upload.  Recorded by
 PersistentPairDispatcher.submit; read by benchmark/program_trace.py.
 Moves verdicts_per_s (n110.replay)."""
 

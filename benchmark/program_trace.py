@@ -5,7 +5,7 @@ The fused program names each stage of its pipeline with a
 ipcache, lattice, verdict, accounting), and each launch records host
 spans that mirror into the profiler trace
 (`PersistentPairDispatcher.submit`: `datapath.launch` over
-`datapath.stack`, `.upload`, `.enqueue`, `.outputs`).  This module
+`datapath.upload`, `.stack`, `.enqueue`, `.outputs`).  This module
 reads the traced run's xplane once (cached by path) and reduces it
 with benchmark/trace.py's interval functions:
 

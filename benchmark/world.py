@@ -1,6 +1,7 @@
-"""The benchmark's world: a Cilium deployment built from a seed through
-the program's public entry points (Daemon, policy_add, regenerate_all,
-ipcache.upsert, ServiceManager, compile_ct, compile_lb).
+"""The benchmark's default world: a Cilium deployment built from a seed
+through the program's public entry points (Daemon, policy_add,
+regenerate_all, ipcache.upsert, ServiceManager, compile_ct,
+compile_lb).
 
 The generators are copies of bench.py's (build_rules, make_flow_pool,
 zipf_picks, pack_pool_pairs): bench.py may change, this file may not.
@@ -10,6 +11,32 @@ pool and packed pairs for the same seed.
 Beside the program's objects the world keeps a plain description of
 what it asked for (rule specs, addresses, services, prefilter), which
 is all that benchmark/reference.py reads.
+
+The world contract.  A configuration without a `world` key is built
+here; one with `"world": "<name>"` is built by
+benchmark/worlds/<name>.py, which the harness finds by that name
+(harness.world_module) as it finds loops and per-layer readers.  A
+world module gives
+
+- `build_world(cfg, rng)`: the world of configuration `cfg`, drawn
+  from `rng`, which the harness seeds from the configuration's
+  `world_seed`;
+- `TINY`: the configuration keys that make the world small enough for
+  the tests on the CPU (benchmark/tests/test_correct.py).
+
+The world is one object; who reads which attribute:
+
+- the harness: `daemon` (fallback_counters) and `timings` (set-up
+  phases on stderr).  It sets `cfg`, the configuration, before the
+  loop is built, so that a loop can read its deployment's own keys;
+- the `replay` loop: `tables`, `pool`, `ct`, `index` and `timings`;
+  it sets `ct_seeded` once it has seeded conntrack;
+- benchmark/reference.py: `specs`, `ep_ip`, `id_ips`, `n_teams`,
+  `services`, `prefilter_cidrs` and `index`.
+
+A world whose rules reference.py cannot express brings its own
+reference, next to its own loop, and that loop imports it, as
+`replay` imports benchmark/reference.py.
 """
 
 from __future__ import annotations
@@ -21,6 +48,21 @@ from types import SimpleNamespace
 
 import numpy as np
 
+# The rule mix: a draw below the first cut makes an L4 rule, below the
+# second an L3-only rule, then CIDR, then HTTP; the rest are Kafka.
+# configs/n110.json documents the same mix as shares.
+RULE_CUTS = (0.84, 0.92, 0.96, 0.99)
+# Each pool flow's chance of each kind (make_flow_pool).
+POOL_MIX = {
+    "l7_bound": 0.025,
+    "junk_ports": 0.10,
+    "egress_to_vip": 0.10,
+    "prefiltered": 0.02,
+    "world": 0.03,
+    "fragments": 0.02,
+}
+# Sizes at which this world is built in the tests on the CPU.
+TINY = dict(rules=1500, endpoints=8, identities=1024, pool=3000)
 
 
 def ip_u32(s: str) -> int:
@@ -33,12 +75,12 @@ def ip_u32(s: str) -> int:
 # ---------------------------------------------------------------------------
 
 
-def build_rules(rng, n_rules, n_endpoints, n_teams):
+def build_rules(rng, n_rules, n_endpoints, n_teams, cuts=RULE_CUTS):
     """A mixed policy: plain L4 (84%), L3-only (8%), CIDR (4%), HTTP L7
-    (3%), Kafka L7 (1%); every rule selects one app (endpoint) and
-    allows one team (identity group).  Returns (rules, all_ports,
-    l7_pairs, specs); specs[i] = (app_idx, kind, team_idx, port,
-    proto, block) with kind in l4/l3/cidr/http/kafka."""
+    (3%), Kafka L7 (1%) at the default `cuts`; every rule selects one
+    app (endpoint) and allows one team (identity group).  Returns
+    (rules, all_ports, l7_pairs, specs); specs[i] = (app_idx, kind,
+    team_idx, port, proto, block) with kind in l4/l3/cidr/http/kafka."""
     from cilium_tpu.labels import LabelArray
     from cilium_tpu.policy.api import (
         EndpointSelector,
@@ -74,7 +116,7 @@ def build_rules(rng, n_rules, n_endpoints, n_teams):
         kind = rng.random()
         sel = es("app", app)
         src = es("team", team)
-        if kind < 0.84:
+        if kind < cuts[0]:
             port = int(plain_ports[int(rng.integers(0, len(plain_ports)))])
             proto = "TCP" if rng.random() < 0.7 else "UDP"
             specs.append(
@@ -89,16 +131,16 @@ def build_rules(rng, n_rules, n_endpoints, n_teams):
                     )
                 ],
             )
-        elif kind < 0.92:
+        elif kind < cuts[1]:
             specs.append((app_idx, "l3", team_idx, 0, 0, -1))
             ingress = IngressRule(from_endpoints=[src])  # L3-only
-        elif kind < 0.96:
+        elif kind < cuts[2]:
             block = int(rng.integers(0, 256))
             specs.append((app_idx, "cidr", -1, 0, 0, block))
             ingress = IngressRule(
                 from_cidr_set=[CIDRRule(cidr=f"198.18.{block}.0/24")]
             )
-        elif kind < 0.99:
+        elif kind < cuts[3]:
             port = http_ports[int(rng.integers(0, len(http_ports)))]
             l7_pairs.append((app_idx, port, team_idx))
             specs.append((app_idx, "http", team_idx, port, 6, -1))
@@ -156,11 +198,11 @@ def build_rules(rng, n_rules, n_endpoints, n_teams):
 
 
 def make_flow_pool(args, rng, ep_ip, id_ips, vips, all_ports, index,
-                   l7_pairs=None, n_teams=1):
-    """A pool of unique flows.  2.5% are proxy-bound L7 traffic (an
-    allowed team member hitting an L7 rule's port at its endpoint),
-    10% junk ports, 10% of egress to service VIPs, 2% prefiltered
-    sources, 3% world sources, 2% fragments."""
+                   l7_pairs=None, n_teams=1, mix=POOL_MIX):
+    """A pool of unique flows.  At the default `mix`, 2.5% are
+    proxy-bound L7 traffic (an allowed team member hitting an L7 rule's
+    port at its endpoint), 10% junk ports, 10% of egress to service
+    VIPs, 2% prefiltered sources, 3% world sources, 2% fragments."""
     n = args.pool
     ep_ids = np.asarray(sorted(ep_ip), np.int64)
     ep_axis = np.asarray([index[int(e)] for e in ep_ids], np.int32)
@@ -169,8 +211,8 @@ def make_flow_pool(args, rng, ep_ip, id_ips, vips, all_ports, index,
     pick_ep = rng.integers(0, len(ep_ids), size=n)
     direction = (rng.random(n) < 0.5).astype(np.uint8)  # 0=in 1=eg
     peer_ip = id_ips[rng.integers(0, len(id_ips), size=n)]
-    pre = rng.random(n) < 0.02
-    world = rng.random(n) < 0.03
+    pre = rng.random(n) < mix["prefiltered"]
+    world = rng.random(n) < mix["world"]
     peer_ip = np.where(
         pre,
         ip_u32("203.0.113.0") + rng.integers(0, 256, size=n),
@@ -180,7 +222,7 @@ def make_flow_pool(args, rng, ep_ip, id_ips, vips, all_ports, index,
             peer_ip,
         ),
     ).astype(np.uint32)
-    to_vip = (direction == 1) & (rng.random(n) < 0.10)
+    to_vip = (direction == 1) & (rng.random(n) < mix["egress_to_vip"])
     vip_arr = np.asarray(vips, np.uint32)
     vip_pick = vip_arr[rng.integers(0, len(vip_arr), size=n)]
 
@@ -195,19 +237,19 @@ def make_flow_pool(args, rng, ep_ip, id_ips, vips, all_ports, index,
     pick_port = rng.integers(0, len(ports), size=n)
     dport = ports[pick_port]
     proto = protos[pick_port]
-    junk = rng.random(n) < 0.10
+    junk = rng.random(n) < mix["junk_ports"]
     dport = np.where(junk, rng.integers(30000, 65536, size=n), dport)
     dport = np.where(to_vip, 80, dport).astype(np.uint16)
     proto = np.where(junk, 6, proto)
     proto = np.where(to_vip, 6, proto).astype(np.uint8)
     sport = rng.integers(1024, 65536, size=n).astype(np.uint16)
-    frag = (rng.random(n) < 0.02).astype(np.uint8)
+    frag = (rng.random(n) < mix["fragments"]).astype(np.uint8)
 
     ep_index = ep_axis[pick_ep].astype(np.uint32)
     if l7_pairs:
         # overlay last so junk/VIP/prefilter mixing can't clobber the
         # L7 flows' defining fields
-        l7 = np.nonzero(rng.random(n) < 0.025)[0]
+        l7 = np.nonzero(rng.random(n) < mix["l7_bound"])[0]
         pick_rule = rng.integers(0, len(l7_pairs), size=len(l7))
         for row, r in zip(l7, pick_rule):
             app_i, port, team_idx = l7_pairs[int(r)]
@@ -285,10 +327,12 @@ def pack_pool_pairs(pool, prng, half: int, k: int, zipf_s=None):
 # ---------------------------------------------------------------------------
 
 
-def build_world(cfg: dict, rng) -> SimpleNamespace:
+def build_world(cfg: dict, rng, rule_cuts=RULE_CUTS,
+                pool_mix=POOL_MIX) -> SimpleNamespace:
     """Endpoints, the identity universe, the policy, services and the
     prefilter of configuration `cfg`, through the program's control
-    plane, and the flow pool."""
+    plane, and the flow pool.  A world module of another mix calls it
+    with its own `rule_cuts` and `pool_mix`."""
     from cilium_tpu.ct.device import compile_ct
     from cilium_tpu.ct.table import CTMap
     from cilium_tpu.daemon import Daemon
@@ -337,7 +381,7 @@ def build_world(cfg: dict, rng) -> SimpleNamespace:
 
     t0 = time.perf_counter()
     rules, all_ports, l7_pairs, specs = build_rules(
-        rng, int(cfg["rules"]), n_eps, n_teams
+        rng, int(cfg["rules"]), n_eps, n_teams, rule_cuts
     )
     d.policy_add(rules)
     timings["policy_add_s"] = time.perf_counter() - t0
@@ -383,7 +427,7 @@ def build_world(cfg: dict, rng) -> SimpleNamespace:
     pool = make_flow_pool(
         SimpleNamespace(pool=int(cfg["pool"])), rng, ep_ip,
         np.asarray(id_ips, np.uint32), [s[0] for s in services],
-        all_ports, index, l7_pairs=l7_pairs, n_teams=n_teams,
+        all_ports, index, l7_pairs=l7_pairs, n_teams=n_teams, mix=pool_mix,
     )
     timings["pool_s"] = time.perf_counter() - t0
     return SimpleNamespace(
