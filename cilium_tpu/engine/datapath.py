@@ -1008,11 +1008,21 @@ class PersistentPairDispatcher:
     dispatch of the device stack), `datapath.enqueue` (the program
     call) and `datapath.outputs` (the per-pair slices).  Nothing waits
     on the device: each span times the host call, so an asynchronous
-    upload shows as a short upload span."""
+    upload shows as a short upload span.
+
+    With `l7` (an l7.fleet.L7Stage) each submit also takes the pair's
+    u32 [2, B] request-id plane, which rides in the same upload; after
+    the program call a `datapath.l7` span dispatches the L7 program
+    (XLA module `jit_l7_program`, jit site `site + ".l7"`) over the K
+    stacked outputs, and each drained result gains an
+    l7.fleet.L7Verdicts.  Its counts (l7.fleet.L7_COUNTS) accumulate
+    on the device in `l7_counts` and fold into
+    metrics.policy_l7_total at `flush()`.  Without `l7` nothing of
+    this runs."""
 
     def __init__(
         self, tables, k_pairs: int, acc, telem,
-        site: str = "datapath.persistent",
+        site: str = "datapath.persistent", l7=None,
     ) -> None:
         self.site = site
         self.tables = tables
@@ -1029,30 +1039,49 @@ class PersistentPairDispatcher:
             site + ".remainder",
         )
         self.launches = 0
+        self.l7 = l7
+        if l7 is not None:
+            self.l7_counts = l7.zero_counts()
+            self._l7_program = tracing.track_jit(l7.program, site + ".l7")
 
-    def submit(self, pair_host: np.ndarray):
-        """Stage one [2, 4, B] host pair; when the K-th arrives the
-        super-batch launches (one dispatch for all K).  Returns the
-        drained per-pair (out_i, out_e) tuples, [] while staging."""
+    def submit(self, pair_host: np.ndarray, req_ids=None):
+        """Stage one [2, 4, B] host pair (and with `l7` its u32 [2, B]
+        request-id plane); when the K-th arrives the super-batch
+        launches (one dispatch for all K).  Returns the drained
+        per-pair (out_i, out_e[, l7 verdicts]) tuples, [] while
+        staging."""
         tracer = tracing.tracer
-        self._staged.append(pair_host)
+        if self.l7 is not None and req_ids is None:
+            raise ValueError("an L7 dispatcher needs each pair's req_ids")
+        self._staged.append((pair_host, req_ids))
         if len(self._staged) < self.k:
             return []
         staged, self._staged = self._staged, []
+        pairs = [p for p, _ in staged]
+        host = pairs
+        if self.l7 is not None:
+            host = pairs + [np.asarray(r, np.uint32) for _, r in staged]
         attrs = {
             "pairs": len(staged),
-            "tuples": sum(p.shape[0] * p.shape[-1] for p in staged),
-            "bytes": sum(p.nbytes for p in staged),
+            "tuples": sum(p.shape[0] * p.shape[-1] for p in pairs),
+            "bytes": sum(a.nbytes for a in host),
         }
         with tracer.span("datapath.launch", site=self.site, attrs=attrs):
             with tracer.span("datapath.upload", site=self.site):
-                uploaded = jax.device_put(staged)
+                uploaded = jax.device_put(host)
             with tracer.span("datapath.stack", site=self.site):
-                stacked = self._stack(*uploaded)
+                stacked = self._stack(*uploaded[: self.k])
             with tracer.span("datapath.enqueue", site=self.site):
                 outs_i, outs_e, self.acc, self.telem = self._program(
                     self.tables, stacked, self.acc, self.telem
                 )
+            if self.l7 is not None:
+                with tracer.span("datapath.l7", site=self.site):
+                    l7v, self.l7_counts = self._l7_program(
+                        self.l7.tables, self.l7.requests, stacked,
+                        outs_i, outs_e, self.l7_counts,
+                        *uploaded[self.k:],
+                    )
             self.launches += 1
             # persistent-program launch accounting for the perf plane:
             # pairs/launches = realized staging depth at scrape time
@@ -1066,23 +1095,42 @@ class PersistentPairDispatcher:
                     )
                     for i in range(self.k)
                 ]
+                if self.l7 is not None:
+                    outs = [
+                        o + (jax.tree.map(lambda a: a[i], l7v),)
+                        for i, o in enumerate(outs)
+                    ]
         return outs
 
     def flush(self):
         """Drain the staged remainder through the per-pair program
         (one launch per leftover pair — still no per-direction
-        dispatch) and return (results, acc, telem).  This is the
-        ONE carry commit point: callers host-read acc/telem here."""
+        dispatch; with `l7` each then through the L7 program) and
+        return (results, acc, telem).  This is the ONE carry commit
+        point: callers host-read acc/telem here, and the L7 counts
+        since the last flush fold into metrics.policy_l7_total and
+        restart from zero."""
         results = []
-        for pair in self._staged:
+        for pair, req_ids in self._staged:
+            pair_dev = jax.device_put(pair)
             out_i, out_e, self.acc, self.telem = (
                 self._pair_fallback(
-                    self.tables, jax.device_put(pair),
-                    self.acc, self.telem,
+                    self.tables, pair_dev, self.acc, self.telem,
                 )
             )
             results.append((out_i, out_e))
+            if self.l7 is not None:
+                one = jax.tree.map(lambda a: a[None], (out_i, out_e))
+                l7v, self.l7_counts = self._l7_program(
+                    self.l7.tables, self.l7.requests, pair_dev[None],
+                    *one, self.l7_counts,
+                    jax.device_put(np.asarray(req_ids, np.uint32)),
+                )
+                results[-1] += (jax.tree.map(lambda a: a[0], l7v),)
         self._staged = []
+        if self.l7 is not None:
+            self.l7.fold_counts(self.l7_counts)
+            self.l7_counts = self.l7.zero_counts()
         return results, self.acc, self.telem
 
 

@@ -16,30 +16,50 @@ datapath+proxy number of BASELINE config 5).
 Scope tables are indexed by the datapath's own outputs: the fused
 verdict exposes the matched L4 slot (`DatapathVerdicts.l4_slot`), so a
 redirected flow's scope is (ep_index, direction, l4_slot) with no
-extra probes.
+extra probes.  Every HTTP rule, header constraints included
+(l7/http.py), and every Kafka rule is decided on the device.
+
+`L7Stage` is the L7 half of the persistent launch path
+(engine.datapath.PersistentPairDispatcher(l7=...)): a program of its
+own, XLA module `jit_l7_program`, chained after the fused L3/L4
+program in the same launch.  It reads that program's stacked outputs
+on the device, takes each tuple's request from a device request table
+(`pack_requests`: one row per request, addressed by a u32 request-id
+plane staged beside the pairs), and returns each tuple's L7 verdict
+and final verdict while it accumulates the L7 counts
+(`L7_COUNTS`).
 """
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass, replace
-from typing import List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 from cilium_tpu.l7.http import (
+    MAX_HEADER_PAIRS,
     HTTPPolicy,
     compile_http_rules,
     evaluate_http_batch,
+    pad_headers,
+    pad_requests,
     resolve_selector_indices,
     specs_from_filter,
+    trim_packed,
 )
 from cilium_tpu.l7.kafka import (
+    MAX_TOPICS,
+    KafkaRequest,
     KafkaRuleSpec,
     KafkaTables,
     compile_kafka_rules,
     evaluate_kafka_batch,
+    pad_kafka_requests,
     rule_spec_from_port_rule,
 )
+from cilium_tpu.metrics import registry as metrics
 from cilium_tpu.policy.l4 import (
     PARSER_TYPE_HTTP as PARSER_HTTP,
     PARSER_TYPE_KAFKA as PARSER_KAFKA,
@@ -155,11 +175,6 @@ def compile_fleet_l7(daemon) -> FleetL7:
     scope_kafka = scope_table(
         kafka.specs if kafka else [], kafka.n_rules if kafka else 0
     )
-    if http and http.host_rules:
-        raise ValueError(
-            "fleet L7 compile does not support header rules on the "
-            "device path (host_rules present)"
-        )
     return FleetL7(
         http=http,
         kafka=kafka,
@@ -178,6 +193,7 @@ def evaluate_fleet_l7(
     known,  # bool [B]
     http_fields: Optional[Tuple] = None,  # (m, ml, p, pl, h, hl)
     kafka_fields: Optional[Tuple] = None,  # pad_kafka_requests order
+    http_headers: Optional[Tuple] = None,  # pad_headers (names, pairs)
 ):
     """L7 verdicts for a batch of redirected flows (traced; call
     inside a jit).  Returns allowed bool [B]: flows whose scope has no
@@ -194,19 +210,314 @@ def evaluate_fleet_l7(
     kind = jnp.asarray(fleet.parser_kind).reshape(-1)[lin]
     allowed = jnp.zeros(ep_index.shape, bool)
     if fleet.http is not None and http_fields is not None:
-        wh = fleet.scope_http.shape[-1]
-        scope = jnp.asarray(fleet.scope_http).reshape(-1, wh)[lin]
         ok, _ = evaluate_http_batch(
             fleet.http.tables, *http_fields, ident_idx, known,
-            scope_bits=scope,
+            scope_bits=scope_rows(fleet.scope_http, lin),
+            headers=http_headers,
         )
         allowed = jnp.where(kind == PARSER_HTTP_ID, ok, allowed)
     if fleet.kafka is not None and kafka_fields is not None:
-        wk = fleet.scope_kafka.shape[-1]
-        scope = jnp.asarray(fleet.scope_kafka).reshape(-1, wk)[lin]
         ok = evaluate_kafka_batch(
             fleet.kafka, *kafka_fields, ident_idx, known,
-            scope_bits=scope,
+            scope_bits=scope_rows(fleet.scope_kafka, lin),
         )
         allowed = jnp.where(kind == PARSER_KAFKA_ID, ok, allowed)
     return allowed
+
+
+def scope_rows(table, lin):
+    """The rule-scope bits u32 [B, W] of each flow's (ep, dir, slot)
+    scope, `lin` its flat index into the [E, 2, Kg, W] table."""
+    import jax.numpy as jnp
+
+    return jnp.asarray(table).reshape(-1, table.shape[-1])[lin]
+
+
+# ---------------------------------------------------------------------------
+# the L7 stage of the persistent launch path
+# ---------------------------------------------------------------------------
+
+# the device's L7 counts, in order: the first three fold into
+# metrics.policy_l7_total{rule=...} at the dispatcher's flush;
+# `overflow` counts the redirected tuples whose request was over the
+# field budgets (decided exactly all the same: fleet_l7_program)
+L7_COUNTS = ("received", "forwarded", "denied", "overflow")
+# tuples evaluated per step of the L7 program's map over a launch: the
+# per-request tensors of one step ([chunk, field bytes, ...]) stay a
+# small share of device memory
+L7_CHUNK = 16384
+# arrays of the compiled fleet at least this large are arguments of the
+# L7 program, not constants folded into it
+_ARG_BYTES = 1 << 16
+_HTTP_COLS = ("method", "method_len", "path", "path_len", "host",
+              "host_len")
+_KAFKA_COLS = ("kind", "version", "client", "topics", "topic_count",
+               "parsed", "checks_client", "kafka_overflow")
+
+
+def _cover(n: int, least: int = 1) -> int:
+    """The smallest power of two of at least max(n, least)."""
+    width = least
+    while width < n:
+        width *= 2
+    return width
+
+
+def _pack(fleet, http, headers, kafka, lm, lp, lh, pairs, topics):
+    """(request table columns, overflow bool [B]) at the given
+    widths; the byte fields cut to the power of two that covers the
+    longest request within them."""
+    m, ml, p, pl, h, hl, overflow = pad_requests(http, lm=lm, lp=lp, lh=lh)
+    if fleet.http is not None:
+        names, pair_ids, hdr_over = pad_headers(
+            fleet.http.tables, headers, max_pairs=pairs)
+    else:  # no policy names a header: nothing to stage
+        names = pair_ids = np.zeros((len(http), pairs), np.uint32)
+        hdr_over = np.zeros(len(http), bool)
+    kf = pad_kafka_requests(fleet.kafka or compile_kafka_rules([], 1),
+                            kafka, max_topics=topics)
+    fits = ~overflow
+    cols = dict(zip(_HTTP_COLS, (
+        trim_packed(m, ml[fits]), ml, trim_packed(p, pl[fits]), pl,
+        trim_packed(h, hl[fits]), hl,
+    )))
+    cols.update(zip(_KAFKA_COLS, (np.asarray(x) for x in kf)))
+    cols.update(hname=names, hpair=pair_ids)
+    return cols, overflow | hdr_over | np.asarray(kf[-1])
+
+
+def pack_requests(
+    fleet: FleetL7,
+    http: Sequence[Tuple[bytes, bytes, bytes]],
+    headers: Sequence[Optional[Dict[str, str]]],
+    kafka: Sequence[KafkaRequest],
+    lm: int = 16,
+    lp: int = 128,
+    lh: int = 64,
+) -> Dict[str, object]:
+    """The request table: row i holds request i's HTTP fields
+    (pad_requests), the headers of the names the fleet's policy names,
+    interned (pad_headers), and its Kafka fields interned against the
+    fleet's Kafka strings (pad_kafka_requests), at the field budgets
+    (lm, lp, lh, MAX_HEADER_PAIRS, kafka.MAX_TOPICS).  `overflow` marks
+    a request over any of them.  Such a request is never decided from
+    truncated fields: `wide` holds the overflowing requests again, at
+    widths that cover them whole, and `wide_row` gives each row's
+    place there (0 where it fits).  Each byte field is cut to the
+    power of two that covers its longest request: positions past a
+    request's length leave the DFA state unchanged.  The three lists
+    are aligned; a row whose flow is of one protocol carries empty
+    fields of the other."""
+    cols, overflow = _pack(fleet, http, headers, kafka, lm, lp, lh,
+                           MAX_HEADER_PAIRS, MAX_TOPICS)
+    out: Dict[str, object] = dict(cols, overflow=overflow)
+    rows = np.nonzero(overflow)[0]
+    out["wide_row"] = np.zeros(len(http), np.int32)
+    if len(rows):
+        out["wide_row"][rows] = np.arange(len(rows), dtype=np.int32)
+        named = (fleet.http.tables.header_names if fleet.http is not None
+                 else {})
+        http_w = [http[i] for i in rows]
+        headers_w = [headers[i] for i in rows]
+        kafka_w = [kafka[i] for i in rows]
+        width = [_cover(max(len(r[f]) for r in http_w), 8) for f in range(3)]
+        wide, wide_over = _pack(
+            fleet, http_w, headers_w, kafka_w, *width,
+            _cover(max(sum(n in named for n in (h or {}))
+                       for h in headers_w)),
+            _cover(max(len(set(k.topics)) for k in kafka_w)),
+        )
+        assert not wide_over.any()
+        out["wide"] = wide
+    return out
+
+
+def _swap(obj, fn):
+    """`obj` (a compiled fleet, nested dataclasses) rebuilt with
+    fn(value) for every field that is not itself a dataclass."""
+    if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
+        return replace(obj, **{
+            f.name: _swap(getattr(obj, f.name), fn)
+            for f in dataclasses.fields(obj) if f.init
+        })
+    return fn(obj)
+
+
+class _Arg(NamedTuple):
+    index: int
+
+
+class L7Verdicts(NamedTuple):
+    """The L7 outcome, u8 [..., 2, B] each (row 0 ingress, 1 egress):
+    `l7_allowed` the L7 verdict of a redirected tuple (0 for a tuple
+    not redirected), `allowed` the final verdict (the L3/L4 verdict,
+    and for a redirected tuple also the L7 one)."""
+
+    l7_allowed: "object"
+    allowed: "object"
+
+
+def _decide(fleet, cols, direction, ep, slot, ident, rows):
+    """Each tuple's L7 verdict through its (ep, direction, slot) scope,
+    its request row `rows` of the request columns `cols`."""
+    import jax.numpy as jnp
+
+    return evaluate_fleet_l7(
+        fleet, ep, jnp.full(ep.shape, direction, jnp.int32), slot,
+        ident, jnp.ones(ep.shape, bool),
+        http_fields=tuple(cols[k][rows] for k in _HTTP_COLS),
+        kafka_fields=tuple(cols[k][rows] for k in _KAFKA_COLS),
+        http_headers=(cols["hname"][rows], cols["hpair"][rows]),
+    )
+
+
+def _l7_direction(fleet, requests, chunk, ep, direction, slot, ident,
+                  rid):
+    """_decide over the request table, in steps of `chunk` tuples
+    (jax.lax.map)."""
+    import jax
+    import jax.numpy as jnp
+
+    n = ep.shape[0]
+    c = min(chunk, n)
+    pad = (-n) % c
+    cols = tuple(
+        jnp.pad(x, (0, pad)).reshape(-1, c) for x in (ep, slot, ident, rid)
+    )
+
+    def step(xs):
+        return _decide(fleet, requests, direction, *xs)
+
+    return jax.lax.map(step, cols).reshape(-1)[:n]
+
+
+def _redecide(fleet, wide, chunk, ep, direction, slot, ident, rows, ok,
+              pending):
+    """`ok` with the tuples marked `pending` decided again from the
+    request table's `wide` columns (their rows `rows`), up to `chunk`
+    of them per step, until none is left (no step when none is)."""
+    import jax
+    import jax.numpy as jnp
+
+    n = ep.shape[0]
+    c = min(chunk, n)
+
+    def step(state):
+        ok, pending = state
+        at = jnp.nonzero(pending, size=c, fill_value=n)[0]  # n: none
+        take = jnp.minimum(at, n - 1)
+        got = _decide(fleet, wide, direction, ep[take], slot[take],
+                      ident[take], rows[take])
+        return (ok.at[at].set(got, mode="drop"),
+                pending.at[at].set(False, mode="drop"))
+
+    return jax.lax.while_loop(lambda s: jnp.any(s[1]), step,
+                              (ok, pending))[0]
+
+
+def fleet_l7_program(fleet: FleetL7, chunk: int = L7_CHUNK):
+    """(jitted program, its table arguments).
+
+    program(tables, requests, pairs [K, 2, 4, B], outs_i, outs_e,
+            counts u32 [4], *req_ids K x u32 [2, B])
+        -> (L7Verdicts of u8 [K, 2, B], counts')
+
+    outs_i/outs_e are the fused program's stacked DatapathVerdicts;
+    `requests` is pack_requests' table on the device.  A tuple is
+    redirected where its proxy_port is set; its scope is (its
+    endpoint, its half's direction, its l4_slot) and its identity the
+    sec_id index.  A redirected tuple whose scope has no parser is
+    denied (fail closed); a direction with no parser anywhere in the
+    fleet runs no matcher.  A tuple whose request is over the field
+    budgets is decided again from the table's `wide` columns, so every
+    verdict is exact.  counts gains (received, forwarded, denied,
+    overflow) = (redirected, L7-allowed, redirected and not
+    L7-allowed, redirected with a request over the budgets).
+    `counts` is donated."""
+    import jax
+    import jax.numpy as jnp
+
+    from cilium_tpu.engine.datapath import flow_batch_from_packed4
+
+    args: list = []
+
+    def lift(value):
+        if isinstance(value, np.ndarray) and value.nbytes >= _ARG_BYTES:
+            args.append(value)
+            return _Arg(len(args) - 1)
+        return value
+
+    skeleton = _swap(fleet, lift)
+    live = tuple(bool(fleet.parser_kind[:, d].any()) for d in (0, 1))
+
+    def l7_program(tables, requests, pairs, outs_i, outs_e, counts,
+                   *req_ids):
+        fl = _swap(skeleton, lambda v: (
+            tables[v.index] if isinstance(v, _Arg) else v))
+        rid_all = jnp.stack(req_ids)  # [K, 2, B]
+        l7_cols, allowed_cols = [], []
+        for d, outs in enumerate((outs_i, outs_e)):
+            shape = outs.proxy_port.shape  # [K, B]
+            rid = rid_all[:, d].reshape(-1)
+            red = outs.proxy_port.reshape(-1) > 0
+            flagged = red & requests["overflow"][rid]
+            if live[d]:
+                ep = flow_batch_from_packed4(
+                    jnp.moveaxis(pairs[:, d], 1, 0)
+                ).ep_index.reshape(-1)
+                slot = outs.l4_slot.reshape(-1).astype(jnp.int32)
+                ident = outs.sec_id.reshape(-1).astype(jnp.int32)
+                ok = _l7_direction(fl, requests, chunk, ep, d, slot,
+                                   ident, rid)
+                if "wide" in requests:
+                    ok = _redecide(fl, requests["wide"], chunk, ep, d,
+                                   slot, ident, requests["wide_row"][rid],
+                                   ok, flagged)
+                l7 = red & ok
+            else:
+                l7 = jnp.zeros_like(red)
+            allowed = outs.allowed.reshape(-1).astype(bool) & (~red | l7)
+            counts = counts + jnp.stack([
+                jnp.sum(x, dtype=jnp.uint32)
+                for x in (red, l7, red & ~l7, flagged)
+            ])
+            l7_cols.append(l7.reshape(shape))
+            allowed_cols.append(allowed.reshape(shape))
+        return L7Verdicts(
+            jnp.stack(l7_cols, axis=1).astype(jnp.uint8),
+            jnp.stack(allowed_cols, axis=1).astype(jnp.uint8),
+        ), counts
+
+    return jax.jit(l7_program, donate_argnums=(5,)), args
+
+
+class L7Stage:
+    """What PersistentPairDispatcher(l7=...) runs after the fused
+    program: the compiled fleet's tables and the request table on the
+    device, and the jitted L7 program (fleet_l7_program)."""
+
+    def __init__(self, fleet: FleetL7, requests: Dict[str, object],
+                 chunk: int = L7_CHUNK) -> None:
+        import jax
+
+        self.fleet = fleet
+        self.program, tables = fleet_l7_program(fleet, chunk)
+        self.tables = jax.device_put(tables)
+        self.requests = jax.device_put(requests)
+        # redirected tuples with a request over the field budgets, as
+        # folded at the dispatcher's flushes
+        self.overflowed = 0
+
+    @staticmethod
+    def zero_counts():
+        import jax
+
+        return jax.device_put(np.zeros(len(L7_COUNTS), np.uint32))
+
+    def fold_counts(self, counts) -> None:
+        """Add drained counts (L7_COUNTS order) to
+        metrics.policy_l7_total{rule} and `overflowed`."""
+        counts = np.asarray(counts)
+        for rule, n in zip(L7_COUNTS[:3], counts):
+            metrics.policy_l7_total.inc(rule, value=int(n))
+        self.overflowed += int(counts[3])

@@ -10,10 +10,14 @@ Reference semantics being reproduced (bit-identically):
     identity through their selector; an entry with EMPTY L7Rules is an
     L7 allow-all for the selected identities (wildcardL3L4Rules,
     repository.go:170).
-  * Header constraints (PortRuleHTTP.Headers) are exact present-match
-    pairs; they stay host-evaluated (like Envoy evaluates them in C++
-    on the host CPU) — rules carrying headers are excluded from the
-    device tables and merged back by `evaluate_with_host_fallback`.
+  * Header constraints (PortRuleHTTP.Headers, server.go:352-366):
+    "Name: value" is an exact match, "Name" a presence match, names
+    case-insensitive.  They compile to device tables over header
+    names and (name, value) pairs interned against the policy's own
+    literals; a request stages the headers of the names the policy
+    names as those ids (`pad_headers`: a value no rule names is 0,
+    which equals no constraint), so the device decides every header
+    rule and the host only interns strings.
 
 Device layout (R rules per port filter, W = ceil(R/32) mask words —
 rule r lives in bit r%32 of word r//32; no 32-rule cap):
@@ -23,13 +27,22 @@ rule r lives in bit r%32 of word r//32; no 32-rule cap):
   ident_rules u32 [N, W] — bit r set ⟺ rule r's selector admits
                            identity index n (includes allow-all
                            pseudo-rules, which also have all fields
-                           absent).
+                           absent);
+  hdr_name/hdr_pair u32 [C], hdr_rules u32 [C, Wh] — header
+                           constraint c (a presence match on name id
+                           hdr_name[c] when hdr_pair[c] is 0, else an
+                           exact match on pair id hdr_pair[c]) and the
+                           rules that require it.  Rules with header
+                           constraints are numbered first, so their
+                           bits fit the leading Wh words.
 
-Requests whose method/path/host exceed the padded field budgets are
-FLAGGED (`overflow`) and re-evaluated host-side by
-`evaluate_with_host_fallback` — never silently truncated: a truncated
-byte tensor could both falsely full-match a prefix-shaped pattern and
-miss a long-match, in either direction.
+Requests whose method/path/host exceed the padded field budgets, or
+that carry more headers of the names the policy names than
+`MAX_HEADER_PAIRS`, are FLAGGED (`overflow`) and decided again whole:
+host-side by `evaluate_with_host_fallback`, or on the device from the
+fleet request table's wide columns (l7/fleet.py) — never silently
+truncated: a truncated byte tensor could both falsely full-match a
+prefix-shaped pattern and miss a long-match, in either direction.
 """
 
 from __future__ import annotations
@@ -39,18 +52,17 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from cilium_tpu.l7.regex_dfa import (
-    DFA,
-    RegexTooComplex,
-    RegexUnsupported,
-    compile_union,
-)
+from cilium_tpu.l7.regex_dfa import DFA, RegexTooComplex, compile_union
 
 # Sanity ceiling only (accept masks are multi-word): guards against a
 # pathological compile blowing up accept-table width, not a semantic
 # limit — the reference's per-filter rule count is bounded by policy
 # size, not a constant.
 MAX_RULES = 4096
+# headers of the policy's names a request stages for the device
+# (excess → the request is flagged `overflow`, never decided from a
+# prefix)
+MAX_HEADER_PAIRS = 8
 
 
 @dataclass
@@ -83,6 +95,20 @@ class HTTPTables:
     ident_rules: np.ndarray  # u32 [N, W] per-identity rule bits
     n_rules: int
     n_words: int
+    # header constraints (module docstring); C may be 0
+    hdr_name: np.ndarray = field(
+        default_factory=lambda: np.zeros(0, np.uint32)
+    )
+    hdr_pair: np.ndarray = field(
+        default_factory=lambda: np.zeros(0, np.uint32)
+    )
+    hdr_rules: np.ndarray = field(
+        default_factory=lambda: np.zeros((0, 1), np.uint32)
+    )
+    # the policy's header literals: lower-cased name → id, (lower-cased
+    # name, value) → id; ids start at 1 (0 = unseen)
+    header_names: Dict[str, int] = field(default_factory=dict)
+    header_pairs: Dict[Tuple[str, str], int] = field(default_factory=dict)
     # strided forms (None = fall back to the byte-at-a-time scan)
     method_sdfa: "Optional[StridedDFA]" = None
     path_sdfa: "Optional[StridedDFA]" = None
@@ -91,13 +117,13 @@ class HTTPTables:
 
 @dataclass
 class HTTPPolicy:
-    """Compiled HTTP policy + host-fallback rules."""
+    """Compiled HTTP policy."""
 
     tables: HTTPTables
-    host_rules: List[HTTPRuleSpec]  # header-carrying rules
-    # Deduped device rules retained for the host path: overflowed
-    # requests (fields beyond the padded budgets) re-evaluate against
-    # these with re.fullmatch instead of the truncated tensors.
+    # Deduped device rules (rule r is bit r of the tables), retained
+    # for the host path: overflowed requests (fields beyond the padded
+    # budgets) re-evaluate against these with re.fullmatch instead of
+    # the truncated tensors.
     device_rules: List[HTTPRuleSpec] = field(default_factory=list)
 
 
@@ -154,20 +180,35 @@ def specs_from_filter(
     return specs
 
 
+def parse_header(header: str) -> Tuple[str, Optional[str]]:
+    """A PortRuleHTTP.Headers entry → (lower-cased name, exact value
+    or None for a presence match), as server.go:352-366 splits it: at
+    the first space, the ':' trimmed from the name ("Name: value"), or
+    a name alone ("Name").  Envoy matches names case-insensitively."""
+    name, space, value = header.partition(" ")
+    return name.rstrip(":").lower(), (value if space else None)
+
+
 def _dedupe_specs(rules: List[HTTPRuleSpec]) -> List[HTTPRuleSpec]:
     """Rules with identical patterns are one device rule with the
     union of their identity sets — allowed = OR over rules, so this
     is semantics-preserving.  The dominant case is the allow-all
     pseudo-rules that every L3-only rule wildcards into each L7
-    filter (repository.go:170): they all collapse to one."""
-    merged: Dict[Tuple[str, str, str, object], set] = {}
-    order: List[Tuple[str, str, str, object]] = []
+    filter (repository.go:170): they all collapse to one.  Rules with
+    header constraints come first (HTTPTables.hdr_rules' words)."""
+    merged: Dict[tuple, set] = {}
+    order: List[tuple] = []
     for rule in rules:
-        key = (rule.method, rule.path, rule.host, rule.scope_key)
+        headers = tuple(sorted(
+            set(map(parse_header, rule.headers)),
+            key=lambda h: (h[0], h[1] is None, h[1] or ""),
+        ))
+        key = (rule.method, rule.path, rule.host, rule.scope_key, headers)
         if key not in merged:
             merged[key] = set()
             order.append(key)
         merged[key].update(rule.identity_indices)
+    order.sort(key=lambda key: not key[4])  # stable
     return [
         HTTPRuleSpec(
             identity_indices=sorted(merged[key]),
@@ -175,9 +216,50 @@ def _dedupe_specs(rules: List[HTTPRuleSpec]) -> List[HTTPRuleSpec]:
             path=key[1],
             host=key[2],
             scope_key=key[3],
+            headers=tuple(
+                name if value is None else f"{name}: {value}"
+                for name, value in key[4]
+            ),
         )
         for key in order
     ]
+
+
+def _header_tables(device_rules: List[HTTPRuleSpec]) -> dict:
+    """The header constraints of the (deduped, header rules first)
+    device rules as HTTPTables' hdr_* fields and interned literals."""
+    names: Dict[str, int] = {}
+    pairs: Dict[Tuple[str, str], int] = {}
+    constraints: Dict[Tuple[int, int], int] = {}
+    n_hdr = 0
+    for i, rule in enumerate(device_rules):
+        if not rule.headers:
+            continue
+        n_hdr = i + 1
+        for header in rule.headers:
+            name, value = parse_header(header)
+            nid = names.setdefault(name, len(names) + 1)
+            pid = (
+                0 if value is None
+                else pairs.setdefault((name, value), len(pairs) + 1)
+            )
+            constraints[(nid, pid)] = constraints.get((nid, pid), 0) | (
+                1 << i
+            )
+    words = max(1, -(-n_hdr // 32))
+    keys = list(constraints)
+    rules = np.zeros((len(keys), words), np.uint32)
+    for c, key in enumerate(keys):
+        mask = constraints[key]
+        for w in range(words):
+            rules[c, w] = (mask >> (32 * w)) & 0xFFFFFFFF
+    return dict(
+        hdr_name=np.asarray([k[0] for k in keys], np.uint32),
+        hdr_pair=np.asarray([k[1] for k in keys], np.uint32),
+        hdr_rules=rules,
+        header_names=names,
+        header_pairs=pairs,
+    )
 
 
 def compile_http_rules(
@@ -185,15 +267,9 @@ def compile_http_rules(
     n_identities: int,
     max_states: int = 4096,
 ) -> HTTPPolicy:
-    """Split rules into device/host sets and build the union DFAs."""
-    device_rules: List[HTTPRuleSpec] = []
-    host_rules: List[HTTPRuleSpec] = []
-    for rule in rules:
-        if rule.headers:
-            host_rules.append(rule)
-            continue
-        device_rules.append(rule)
-    device_rules = _dedupe_specs(device_rules)
+    """Dedupe the rules and build the union DFAs and header tables:
+    every rule, header-carrying or not, is decided on the device."""
+    device_rules = _dedupe_specs(list(rules))
     if len(device_rules) > MAX_RULES:
         raise RegexTooComplex(
             f"more than {MAX_RULES} device HTTP rules per filter"
@@ -219,10 +295,7 @@ def compile_http_rules(
                 patterns.append("[^\\x00-\\xff]")  # matches nothing
             else:
                 patterns.append(pattern)
-        try:
-            dfa = compile_union(patterns, max_states=max_states)
-        except (RegexUnsupported, RegexTooComplex):
-            raise
+        dfa = compile_union(patterns, max_states=max_states)
         return dfa, _to_words(absent)
 
     method_dfa, absent_method = union_for("method")
@@ -247,10 +320,9 @@ def compile_http_rules(
         ident_rules=ident_rules,
         n_rules=len(device_rules),
         n_words=n_words,
+        **_header_tables(device_rules),
     )
-    return HTTPPolicy(
-        tables=tables, host_rules=host_rules, device_rules=device_rules
-    )
+    return HTTPPolicy(tables=tables, device_rules=device_rules)
 
 
 # ---------------------------------------------------------------------------
@@ -474,6 +546,30 @@ def _dfa_scan(dfa: DFA, data, lengths):
     return accept[state]
 
 
+def _header_fail(tables: HTTPTables, headers):
+    """u32 [B, Wh]: the header rules each request fails — some header
+    constraint of the rule has no staged pair that satisfies it.
+    `headers` = (name ids, pair ids) u32 [B, H] (pad_headers); None
+    means no request carries a header."""
+    import jax
+    import jax.numpy as jnp
+
+    want_name = jnp.asarray(tables.hdr_name)[None, None, :]  # [1,1,C]
+    want_pair = jnp.asarray(tables.hdr_pair)[None, None, :]
+    if headers is None:
+        sat = jnp.zeros((1, want_name.shape[-1]), bool)
+    else:
+        names, pairs = (jnp.asarray(a)[:, :, None] for a in headers)
+        sat = jnp.any(
+            jnp.where(want_pair == 0, names == want_name, pairs == want_pair),
+            axis=1,
+        )  # [B, C]
+    need = jnp.where(
+        sat[:, :, None], jnp.uint32(0), jnp.asarray(tables.hdr_rules)[None]
+    )  # [B, C, Wh]
+    return jax.lax.reduce(need, jnp.uint32(0), jax.lax.bitwise_or, (1,))
+
+
 def evaluate_http_batch(
     tables: HTTPTables,
     method: "np.ndarray",  # u8 [B, Lm]
@@ -485,6 +581,7 @@ def evaluate_http_batch(
     ident_idx: "np.ndarray",  # i32 [B] identity index (from engine._index)
     known: "np.ndarray",  # bool [B]
     scope_bits=None,  # u32 [B, W] per-flow rule-scope mask (fleet mode)
+    headers=None,  # (name ids, pair ids) u32 [B, H] from pad_headers
 ):
     """Returns (allowed bool [B], matched_rules u32 [B, W])."""
     import jax.numpy as jnp
@@ -505,6 +602,10 @@ def evaluate_http_batch(
         & (acc_p | jnp.asarray(tables.absent_path)[None, :])
         & (acc_h | jnp.asarray(tables.absent_host)[None, :])
     )
+    if tables.hdr_rules.shape[0]:
+        fail = _header_fail(tables, headers)
+        pad = matched.shape[1] - fail.shape[1]
+        matched = matched & ~jnp.pad(fail, ((0, 0), (0, pad)))
     ident_bits = jnp.asarray(tables.ident_rules)[
         jnp.clip(ident_idx, 0, tables.ident_rules.shape[0] - 1)
     ]  # [B, W]
@@ -529,7 +630,8 @@ def http_rule_matches_host(
     headers: Optional[Dict[str, str]] = None,
 ) -> bool:
     """Host reference matcher (Python re.fullmatch ≙ Envoy regex
-    HeaderMatcher full-match)."""
+    HeaderMatcher full-match).  `headers` maps lower-cased names to
+    values."""
     import re
 
     if rule.method and not re.fullmatch(
@@ -541,18 +643,43 @@ def http_rule_matches_host(
     if rule.host and not re.fullmatch(rule.host.encode(), host, re.DOTALL):
         return False
     for header in rule.headers:
-        # "Name: value" exact or "Name" presence (server.go:352-366)
-        if ":" in header:
-            name, _, value = header.partition(":")
-            want = value.strip()
-        else:
-            name, want = header, None
-        got = (headers or {}).get(name.strip().lower())
+        name, want = parse_header(header)
+        got = (headers or {}).get(name)
         if got is None:
             return False
         if want is not None and got != want:
             return False
     return True
+
+
+def pad_headers(
+    tables: HTTPTables,
+    headers: Sequence[Optional[Dict[str, str]]],
+    max_pairs: int = MAX_HEADER_PAIRS,
+):
+    """Per-request header dicts (lower-cased name → value; None = no
+    headers) → (name ids u32 [B, H], pair ids u32 [B, H], overflow bool
+    [B]), interned against the policy's header literals.  Only headers
+    whose name the policy names are staged: any other can satisfy no
+    constraint.  A named header with a value no rule names stages its
+    name id and pair id 0.  A request with more than `max_pairs` named
+    headers is flagged `overflow` and its device verdict must be
+    discarded."""
+    b = len(headers)
+    names = np.zeros((b, max_pairs), np.uint32)
+    pairs = np.zeros((b, max_pairs), np.uint32)
+    overflow = np.zeros(b, bool)
+    for i, hdrs in enumerate(headers):
+        items = [
+            (tables.header_names[name],
+             tables.header_pairs.get((name, value), 0))
+            for name, value in (hdrs or {}).items()
+            if name in tables.header_names
+        ]
+        overflow[i] = len(items) > max_pairs
+        for j, (nid, pid) in enumerate(items[:max_pairs]):
+            names[i, j], pairs[i, j] = nid, pid
+    return names, pairs, overflow
 
 
 def pad_requests(
@@ -567,8 +694,8 @@ def pad_requests(
     A field longer than its budget is NOT silently truncated into the
     tensors-with-shorter-length (that would corrupt full-match
     semantics in both directions); the row is flagged `overflow` and
-    must be routed to the host matcher (evaluate_with_host_fallback
-    does this).  The tensor row still carries the truncated prefix so
+    must be decided again whole (evaluate_with_host_fallback on the
+    host, l7.fleet's wide pass on the device).  The tensor row still carries the truncated prefix so
     shapes stay static, but its device verdict is discarded."""
     b = len(requests)
     method = np.zeros((b, lm), dtype=np.uint8)
@@ -613,54 +740,32 @@ def evaluate_with_host_fallback(
     lp: int = 128,
     lh: int = 64,
 ) -> np.ndarray:
-    """Full HTTP policy verdict: device DFAs + host-side merge.
-
-    Reference semantics (pkg/envoy/server.go:316,448 +
-    envoy/cilium_l7policy.cc): a request is allowed if ANY rule of the
-    filter matches — including header-carrying rules, which the device
-    tables exclude.  Three host merges over the device verdict:
-
-      1. header rules (policy.host_rules): evaluated with re.fullmatch
-         + header present/exact checks, OR-ed into the device verdict;
-      2. overflow rows (fields beyond the padded budgets): the device
-         verdict for those rows is discarded and recomputed from
-         policy.device_rules host-side — never decided from truncated
-         bytes;
-      3. unknown identities stay denied.
-
-    Returns allowed bool [B].
+    """Full HTTP policy verdict: device DFAs and header tables, with
+    overflow rows (fields beyond the padded budgets, or more header
+    pairs than staged) re-decided host-side from policy.device_rules
+    by re.fullmatch and the header checks — never from truncated
+    bytes.  Unknown identities stay denied.  Returns allowed bool [B].
     """
     packed = pad_requests(requests, lm=lm, lp=lp, lh=lh)
     m, mlen, p, plen, h, hlen, overflow = packed
+    hdrs = list(headers) if headers is not None else [None] * len(requests)
+    names, pairs, hdr_overflow = pad_headers(policy.tables, hdrs)
     allowed_dev, _ = evaluate_http_batch(
         policy.tables,
         trim_packed(m, mlen), mlen,
         trim_packed(p, plen), plen,
         trim_packed(h, hlen), hlen,
         ident_idx, known,
+        headers=(names, pairs),
     )
     allowed = np.asarray(allowed_dev).copy()
     ident_idx = np.asarray(ident_idx)
     known = np.asarray(known)
-
-    # 2: overflowed rows re-evaluate the device rules host-side.
-    for i in np.nonzero(overflow)[0]:
+    for i in np.nonzero(overflow | hdr_overflow)[0]:
         mm, pp, hh = requests[i]
         allowed[i] = bool(known[i]) and any(
             int(ident_idx[i]) in spec.identity_indices
-            and http_rule_matches_host(spec, mm, pp, hh)
+            and http_rule_matches_host(spec, mm, pp, hh, hdrs[i])
             for spec in policy.device_rules
         )
-
-    # 1: header rules can only widen (OR semantics across rules).
-    if policy.host_rules:
-        for i in np.nonzero(~allowed & known)[0]:
-            mm, pp, hh = requests[i]
-            hdrs = headers[i] if headers is not None else None
-            if any(
-                int(ident_idx[i]) in spec.identity_indices
-                and http_rule_matches_host(spec, mm, pp, hh, hdrs)
-                for spec in policy.host_rules
-            ):
-                allowed[i] = True
     return allowed

@@ -197,12 +197,15 @@ def compile_kafka_rules(
 
 
 def pad_kafka_requests(
-    tables: KafkaTables, requests: Sequence[KafkaRequest]
+    tables: KafkaTables,
+    requests: Sequence[KafkaRequest],
+    max_topics: int = MAX_TOPICS,
 ):
     """Requests → integer tensors (strings resolved via the tables'
     interner; unseen strings become 0 ≠ any rule value).
 
-    A request with more unique topics than the tensor row holds is
+    A request with more unique topics than the tensor row holds
+    (`max_topics`) is
     FLAGGED `overflow` (last return) — its device verdict must be
     discarded and the request re-run through matches_rules_host
     (evaluate_with_host_fallback does this)."""
@@ -210,7 +213,7 @@ def pad_kafka_requests(
     kind = np.zeros(b, dtype=np.int32)
     version = np.zeros(b, dtype=np.int32)
     client = np.zeros(b, dtype=np.uint32)
-    topics = np.zeros((b, MAX_TOPICS), dtype=np.uint32)
+    topics = np.zeros((b, max_topics), dtype=np.uint32)
     # Sentinel for "no topic in this slot": topic ids are ≥1, and
     # 0xFFFFFFFF never equals an interned id.
     topics[:] = 0xFFFFFFFF
@@ -224,9 +227,9 @@ def pad_kafka_requests(
         client[i] = tables.interner.lookup(request.client_id)
         # MatchesRule dedupes topics via reqTopicsMap (policy.go:205)
         uniq = list(dict.fromkeys(request.topics))
-        if len(uniq) > MAX_TOPICS:
+        if len(uniq) > max_topics:
             overflow[i] = True
-            uniq = uniq[:MAX_TOPICS]
+            uniq = uniq[:max_topics]
         for j, t in enumerate(uniq):
             topics[i, j] = tables.interner.lookup(t)
         topic_count[i] = len(uniq)

@@ -460,8 +460,8 @@ class Proxy:
         log: bool = True,
     ):
         """Batched HTTP request verdicts through this redirect's
-        compiled policy (device DFAs + host fallback for header rules
-        and over-length fields).  Returns allowed bool [B]; emits one
+        compiled policy (device DFAs and header tables; the host
+        re-decides only over-budget requests).  Returns allowed bool [B]; emits one
         access-log record per request (verdict Forwarded/Denied, like
         cilium_l7policy.cc's 403 + accesslog)."""
         from cilium_tpu.l7.http import evaluate_with_host_fallback

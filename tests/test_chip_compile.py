@@ -119,6 +119,37 @@ def test_fused_programs_compile_for_v5e(program, headline, one_chip):
     )
 
 
+def test_l7_program_compiles_for_v5e(one_chip):
+    """The L7 program (l7.fleet.fleet_l7_program) over the persistent
+    program's outputs at K pairs of B tuples per direction, for a small
+    fleet of HTTP (Host and header rules among them) and Kafka rules."""
+    from cilium_tpu.engine.datapath import persistent_pair_program
+    from cilium_tpu.engine.verdict import (
+        make_counter_buffers,
+        make_telemetry_buffers,
+    )
+    from cilium_tpu.l7.fleet import L7_COUNTS, fleet_l7_program
+    from tests.test_l7_datapath import build_world, request_table
+
+    _, tables, _, fleet, _ = build_world()
+    pairs = jax.ShapeDtypeStruct((K, 2, 4, B), jnp.uint32)
+    outs = jax.eval_shape(
+        persistent_pair_program(K), tables, pairs,
+        make_counter_buffers(tables.policy), make_telemetry_buffers(),
+    )[:2]
+    on_chip = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (pairs, outs),
+    )
+    program, args = fleet_l7_program(fleet)
+    _compile(
+        program, _shapes(args, one_chip),
+        _shapes(request_table(fleet), one_chip), on_chip[0], *on_chip[1],
+        _shapes(np.zeros(len(L7_COUNTS), np.uint32), one_chip),
+        *[jax.ShapeDtypeStruct((2, B), jnp.uint32, sharding=one_chip)] * K,
+    )
+
+
 def test_lattice_evaluate_batch_compiles_for_v5e(headline, one_chip):
     from cilium_tpu.engine.verdict import TupleBatch, evaluate_batch
 

@@ -20,6 +20,7 @@ from cilium_tpu.l7.http import (
     evaluate_http_batch,
     evaluate_with_host_fallback,
     http_rule_matches_host,
+    pad_headers,
     pad_requests,
 )
 from cilium_tpu.l7.kafka import (
@@ -119,7 +120,7 @@ def test_kafka_200_rules_multiword():
 
 
 # ---------------------------------------------------------------------------
-# host fallback: headers + overflow
+# headers + overflow
 # ---------------------------------------------------------------------------
 
 
@@ -141,7 +142,10 @@ def test_header_rule_reaches_verdict():
         ),
     ]
     policy = compile_http_rules(specs, 4)
-    assert len(policy.host_rules) == 1
+    # the header rule is decided on the device: its verdicts equal the
+    # host matcher's
+    assert len(policy.device_rules) == 2
+    assert policy.tables.hdr_rules.shape[0] == 1
 
     requests = [
         (b"GET", b"/secret", b""),
@@ -151,6 +155,17 @@ def test_header_rule_reaches_verdict():
     headers = [{"x-token": "abc"}, {"x-token": "nope"}, None]
     ident = np.zeros(3, dtype=np.int32)
     known = np.ones(3, dtype=bool)
+    host = [
+        any(http_rule_matches_host(s, *r, hd) for s in policy.device_rules)
+        for r, hd in zip(requests, headers)
+    ]
+    m, ml, p, pl, h, hl, _ = pad_requests(requests)
+    names, pairs, _ = pad_headers(policy.tables, headers)
+    device, _ = evaluate_http_batch(
+        policy.tables, m, ml, p, pl, h, hl, ident, known,
+        headers=(names, pairs),
+    )
+    assert np.asarray(device).tolist() == host
     got = evaluate_with_host_fallback(
         policy, requests, ident, known, headers
     )
@@ -158,8 +173,8 @@ def test_header_rule_reaches_verdict():
 
 
 def test_header_only_policy_no_device_rules():
-    """A filter whose ONLY rules carry headers: the device table is
-    empty and everything rides the host path."""
+    """A filter whose ONLY rules carry headers: the header tables
+    alone decide it, on the device."""
     specs = [
         HTTPRuleSpec(
             identity_indices=[1], headers=("X-Allow",)

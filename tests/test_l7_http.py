@@ -14,6 +14,7 @@ from cilium_tpu.l7.http import (
     compile_http_rules,
     evaluate_http_batch,
     http_rule_matches_host,
+    pad_headers,
     pad_requests,
 )
 from cilium_tpu.l7.regex_dfa import (
@@ -153,7 +154,7 @@ def test_http_device_matcher_end_to_end():
         HTTPRuleSpec(identity_indices=[2]),  # L7 allow-all for id 2
     ]
     policy = compile_http_rules(rules, n_identities=8)
-    assert not policy.host_rules
+    assert policy.tables.hdr_rules.shape[0] == 0  # no header constraint
 
     requests = [
         (b"GET", b"/public/index.html", b""),   # rule 0
@@ -182,26 +183,50 @@ def test_http_device_matcher_end_to_end():
 
 
 def test_http_host_rule_split_and_headers():
+    """A header-carrying rule is a device rule: its exact and presence
+    constraints land in the header tables, and the device verdict
+    equals the host matcher for a right, a wrong and an absent value,
+    a header name in another case and a wrong method."""
     rules = [
         HTTPRuleSpec(
             identity_indices=[0],
             method="GET",
             headers=("X-Token: secret",),
         ),
+        HTTPRuleSpec(identity_indices=[0], path="/p", headers=("X-Flag",)),
     ]
     policy = compile_http_rules(rules, n_identities=4)
-    assert len(policy.host_rules) == 1
-    rule = policy.host_rules[0]
-    assert http_rule_matches_host(
-        rule, b"GET", b"/", b"", {"x-token": "secret"}
+    assert len(policy.device_rules) == 2
+    assert policy.tables.hdr_rules.shape[0] == 2
+    cases = [
+        (b"GET", b"/", {"x-token": "secret"}),
+        (b"GET", b"/", {"x-token": "wrong"}),
+        (b"GET", b"/", {}),
+        (b"POST", b"/", {"x-token": "secret"}),
+        (b"PUT", b"/p", {"x-flag": ""}),
+        (b"PUT", b"/p", {"x-other": "1"}),
+    ]
+    want = [
+        any(http_rule_matches_host(r, m, p, b"", h)
+            for r in policy.device_rules)
+        for m, p, h in cases
+    ]
+    assert want == [True, False, False, False, True, False]
+    m, ml, p, pl, h, hl, _ = pad_requests([(m, p, b"") for m, p, _ in cases])
+    names, pairs, overflow = pad_headers(policy.tables, [h for *_, h in cases])
+    assert not overflow.any()
+    allowed, _ = evaluate_http_batch(
+        policy.tables, m, ml, p, pl, h, hl,
+        ident_idx=np.zeros(len(cases), dtype=np.int32),
+        known=np.ones(len(cases), dtype=bool),
+        headers=(names, pairs),
     )
-    assert not http_rule_matches_host(
-        rule, b"GET", b"/", b"", {"x-token": "wrong"}
-    )
-    assert not http_rule_matches_host(rule, b"GET", b"/", b"", {})
-    assert not http_rule_matches_host(
-        rule, b"POST", b"/", b"", {"x-token": "secret"}
-    )
+    assert np.asarray(allowed).tolist() == want
+    # the rule spelled with the name in another case is the same rule
+    upper = compile_http_rules(
+        [HTTPRuleSpec(identity_indices=[0], method="GET",
+                      headers=("x-TOKEN: secret",))], n_identities=4)
+    assert upper.tables.header_pairs == policy.tables.header_pairs
 
 
 def test_http_unknown_identity_denied():
