@@ -1017,8 +1017,10 @@ class PersistentPairDispatcher:
     stacked outputs, and each drained result gains an
     l7.fleet.L7Verdicts.  Its counts (l7.fleet.L7_COUNTS) accumulate
     on the device in `l7_counts` and fold into
-    metrics.policy_l7_total at `flush()`.  Without `l7` nothing of
-    this runs."""
+    metrics.policy_l7_total at `flush()`; each call's matcher tally
+    (L7Verdicts.decided) is kept in `l7_decided` until then and folds
+    into metrics.policy_l7_matcher_tuples_total.  Without `l7` nothing
+    of this runs."""
 
     def __init__(
         self, tables, k_pairs: int, acc, telem,
@@ -1042,6 +1044,7 @@ class PersistentPairDispatcher:
         self.l7 = l7
         if l7 is not None:
             self.l7_counts = l7.zero_counts()
+            self.l7_decided = []
             self._l7_program = tracing.track_jit(l7.program, site + ".l7")
 
     def submit(self, pair_host: np.ndarray, req_ids=None):
@@ -1077,11 +1080,8 @@ class PersistentPairDispatcher:
                 )
             if self.l7 is not None:
                 with tracer.span("datapath.l7", site=self.site):
-                    l7v, self.l7_counts = self._l7_program(
-                        self.l7.tables, self.l7.requests, stacked,
-                        outs_i, outs_e, self.l7_counts,
-                        *uploaded[self.k:],
-                    )
+                    l7v = self._run_l7(stacked, outs_i, outs_e,
+                                       uploaded[self.k:])
             self.launches += 1
             # persistent-program launch accounting for the perf plane:
             # pairs/launches = realized staging depth at scrape time
@@ -1102,6 +1102,19 @@ class PersistentPairDispatcher:
                     ]
         return outs
 
+    def _run_l7(self, pairs, outs_i, outs_e, req_ids):
+        """The L7 program over stacked pairs and their fused outputs:
+        its counts accumulate in `l7_counts`, its matcher tally (where
+        it reports one) joins `l7_decided`, and its verdicts come back
+        without the tally, to be sliced per pair."""
+        l7v, self.l7_counts = self._l7_program(
+            self.l7.tables, self.l7.requests, pairs, outs_i, outs_e,
+            self.l7_counts, *req_ids,
+        )
+        if l7v.decided is not None:
+            self.l7_decided.append(l7v.decided)
+        return l7v._replace(decided=None)
+
     def flush(self):
         """Drain the staged remainder through the per-pair program
         (one launch per leftover pair — still no per-direction
@@ -1121,16 +1134,16 @@ class PersistentPairDispatcher:
             results.append((out_i, out_e))
             if self.l7 is not None:
                 one = jax.tree.map(lambda a: a[None], (out_i, out_e))
-                l7v, self.l7_counts = self._l7_program(
-                    self.l7.tables, self.l7.requests, pair_dev[None],
-                    *one, self.l7_counts,
-                    jax.device_put(np.asarray(req_ids, np.uint32)),
+                l7v = self._run_l7(
+                    pair_dev[None], *one,
+                    [jax.device_put(np.asarray(req_ids, np.uint32))],
                 )
                 results[-1] += (jax.tree.map(lambda a: a[0], l7v),)
         self._staged = []
         if self.l7 is not None:
-            self.l7.fold_counts(self.l7_counts)
+            self.l7.fold_counts(self.l7_counts, self.l7_decided)
             self.l7_counts = self.l7.zero_counts()
+            self.l7_decided = []
         return results, self.acc, self.telem
 
 

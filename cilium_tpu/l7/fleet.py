@@ -201,13 +201,7 @@ def evaluate_fleet_l7(
     closed, as the proxy denies without a NetworkPolicy)."""
     import jax.numpy as jnp
 
-    e_count, _, kg = fleet.parser_kind.shape
-    lin = (
-        ep_index.astype(jnp.int32) * (2 * kg)
-        + direction.astype(jnp.int32) * kg
-        + jnp.clip(l4_slot, 0, kg - 1)
-    )
-    kind = jnp.asarray(fleet.parser_kind).reshape(-1)[lin]
+    lin, kind = scope_kind(fleet, ep_index, direction, l4_slot)
     allowed = jnp.zeros(ep_index.shape, bool)
     if fleet.http is not None and http_fields is not None:
         ok, _ = evaluate_http_batch(
@@ -223,6 +217,20 @@ def evaluate_fleet_l7(
         )
         allowed = jnp.where(kind == PARSER_KAFKA_ID, ok, allowed)
     return allowed
+
+
+def scope_kind(fleet, ep_index, direction, l4_slot):
+    """(lin, kind): each flow's flat (ep, dir, slot) scope index into
+    the [E, 2, Kg, ...] scope tables, and its scope's PARSER_*_ID."""
+    import jax.numpy as jnp
+
+    _, _, kg = fleet.parser_kind.shape
+    lin = (
+        jnp.asarray(ep_index).astype(jnp.int32) * (2 * kg)
+        + jnp.asarray(direction).astype(jnp.int32) * kg
+        + jnp.clip(l4_slot, 0, kg - 1)
+    )
+    return lin, jnp.asarray(fleet.parser_kind).reshape(-1)[lin]
 
 
 def scope_rows(table, lin):
@@ -242,7 +250,12 @@ def scope_rows(table, lin):
 # `overflow` counts the redirected tuples whose request was over the
 # field budgets (decided exactly all the same: fleet_l7_program)
 L7_COUNTS = ("received", "forwarded", "denied", "overflow")
-# tuples evaluated per step of the L7 program's map over a launch: the
+# the matchers, in the order of L7Verdicts.decided: the tuples each
+# decided (the redirected tuples whose scope has its parser), folded
+# into metrics.policy_l7_matcher_tuples_total{parser=...} at the
+# dispatcher's flush
+L7_MATCHERS = ("http", "kafka")
+# tuples evaluated per step of a matcher's loop over a launch: the
 # per-request tensors of one step ([chunk, field bytes, ...]) stay a
 # small share of device memory
 L7_CHUNK = 16384
@@ -351,44 +364,91 @@ class L7Verdicts(NamedTuple):
     """The L7 outcome, u8 [..., 2, B] each (row 0 ingress, 1 egress):
     `l7_allowed` the L7 verdict of a redirected tuple (0 for a tuple
     not redirected), `allowed` the final verdict (the L3/L4 verdict,
-    and for a redirected tuple also the L7 one)."""
+    and for a redirected tuple also the L7 one).  `decided`, u32
+    [len(L7_MATCHERS)] on the L7 program's output (None on one pair's
+    slice): the tuples the HTTP and the Kafka matcher decided in that
+    call."""
 
     l7_allowed: "object"
     allowed: "object"
+    decided: "object" = None
 
 
-def _decide(fleet, cols, direction, ep, slot, ident, rows):
+def _decide(fleet, cols, direction, ep, slot, ident, rows,
+            parsers=(PARSER_HTTP_ID, PARSER_KAFKA_ID)):
     """Each tuple's L7 verdict through its (ep, direction, slot) scope,
-    its request row `rows` of the request columns `cols`."""
+    its request row `rows` of the request columns `cols`, by the
+    matchers of `parsers` alone (a tuple of another parser is
+    denied)."""
     import jax.numpy as jnp
 
+    http = PARSER_HTTP_ID in parsers
+    kafka = PARSER_KAFKA_ID in parsers
     return evaluate_fleet_l7(
         fleet, ep, jnp.full(ep.shape, direction, jnp.int32), slot,
         ident, jnp.ones(ep.shape, bool),
-        http_fields=tuple(cols[k][rows] for k in _HTTP_COLS),
-        kafka_fields=tuple(cols[k][rows] for k in _KAFKA_COLS),
-        http_headers=(cols["hname"][rows], cols["hpair"][rows]),
+        http_fields=(tuple(cols[k][rows] for k in _HTTP_COLS)
+                     if http else None),
+        kafka_fields=(tuple(cols[k][rows] for k in _KAFKA_COLS)
+                      if kafka else None),
+        http_headers=((cols["hname"][rows], cols["hpair"][rows])
+                      if http else None),
     )
 
 
 def _l7_direction(fleet, requests, chunk, ep, direction, slot, ident,
-                  rid):
-    """_decide over the request table, in steps of `chunk` tuples
-    (jax.lax.map)."""
+                  rid, red):
+    """(ok bool [n], decided u32 [2]): each redirected tuple's L7
+    verdict, each matcher run only over the tuples it decides.
+
+    The redirected tuples whose scope has the HTTP parser, then those
+    whose scope has the Kafka parser, are put in front of the others
+    by one stable counting partition (per-class ranks by cumsum, one
+    scatter of the tuple positions).  Each matcher then runs over its
+    class alone in steps of `chunk` tuples, as many as its count
+    needs (a fori_loop with a traced trip count: no step for an empty
+    class); a step's slots past
+    the count read a real tuple and are dropped.  Every other tuple is
+    left False: not redirected, or redirected to a scope with no
+    parser (denied, fail closed).  `decided` counts each class
+    (http, kafka) whose matcher ran."""
     import jax
     import jax.numpy as jnp
 
     n = ep.shape[0]
     c = min(chunk, n)
-    pad = (-n) % c
-    cols = tuple(
-        jnp.pad(x, (0, pad)).reshape(-1, c) for x in (ep, slot, ident, rid)
-    )
+    _, kind = scope_kind(fleet, ep, direction, slot)
+    parsers = [p for p, compiled in ((PARSER_HTTP_ID, fleet.http),
+                                     (PARSER_KAFKA_ID, fleet.kafka))
+               if compiled is not None]
+    ok = jnp.zeros(n, bool)
+    decided = jnp.zeros(len(L7_MATCHERS), jnp.uint32)
+    if not parsers:
+        return ok, decided
+    member = jnp.stack([red & (kind == p) for p in parsers], axis=1)
+    rank = jnp.cumsum(member, axis=0, dtype=jnp.int32) - 1  # [n, P]
+    count = rank[-1] + 1  # [P]
+    first = jnp.cumsum(count) - count  # each class's first position
+    dest = jnp.where(member.any(axis=1),
+                     jnp.sum(jnp.where(member, first + rank, 0), axis=1),
+                     n + c)  # n + c: dropped
+    # the tuple positions class by class; c slots of padding keep the
+    # last step's slice from being clamped back over its class
+    order = jnp.zeros(n + c, jnp.int32).at[dest].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
 
-    def step(xs):
-        return _decide(fleet, requests, direction, *xs)
+    for j, parser in enumerate(parsers):
+        def step(i, ok, j=j, parser=parser):
+            take = jax.lax.dynamic_slice(order, (first[j] + i * c,), (c,))
+            got = _decide(fleet, requests, direction, ep[take],
+                          slot[take], ident[take], rid[take], (parser,))
+            real = i * c + jnp.arange(c) < count[j]
+            return ok.at[jnp.where(real, take, n)].set(got, mode="drop")
 
-    return jax.lax.map(step, cols).reshape(-1)[:n]
+        ok = jax.lax.fori_loop(0, (count[j] + c - 1) // c, step, ok)
+        decided = decided.at[parser - PARSER_HTTP_ID].set(
+            count[j].astype(jnp.uint32))
+    return ok, decided
 
 
 def _redecide(fleet, wide, chunk, ep, direction, slot, ident, rows, ok,
@@ -419,20 +479,24 @@ def fleet_l7_program(fleet: FleetL7, chunk: int = L7_CHUNK):
     """(jitted program, its table arguments).
 
     program(tables, requests, pairs [K, 2, 4, B], outs_i, outs_e,
-            counts u32 [4], *req_ids K x u32 [2, B])
+            counts u32 [len(L7_COUNTS)], *req_ids K x u32 [2, B])
         -> (L7Verdicts of u8 [K, 2, B], counts')
 
     outs_i/outs_e are the fused program's stacked DatapathVerdicts;
     `requests` is pack_requests' table on the device.  A tuple is
     redirected where its proxy_port is set; its scope is (its
     endpoint, its half's direction, its l4_slot) and its identity the
-    sec_id index.  A redirected tuple whose scope has no parser is
-    denied (fail closed); a direction with no parser anywhere in the
-    fleet runs no matcher.  A tuple whose request is over the field
-    budgets is decided again from the table's `wide` columns, so every
-    verdict is exact.  counts gains (received, forwarded, denied,
-    overflow) = (redirected, L7-allowed, redirected and not
-    L7-allowed, redirected with a request over the budgets).
+    sec_id index.  The HTTP matcher decides only the redirected tuples
+    whose scope has the HTTP parser, the Kafka matcher only those
+    whose scope has the Kafka parser (_l7_direction); a redirected
+    tuple whose scope has no parser is denied (fail closed); a
+    direction with no parser anywhere in the fleet runs no matcher.  A
+    tuple whose request is over the field budgets is decided again
+    from the table's `wide` columns, so every verdict is exact.
+    counts gains (received, forwarded, denied, overflow) =
+    (redirected, L7-allowed, redirected and not L7-allowed, redirected
+    with a request over the budgets); the verdicts' `decided` holds
+    the tuples the HTTP and the Kafka matcher decided in this call.
     `counts` is donated."""
     import jax
     import jax.numpy as jnp
@@ -456,19 +520,21 @@ def fleet_l7_program(fleet: FleetL7, chunk: int = L7_CHUNK):
             tables[v.index] if isinstance(v, _Arg) else v))
         rid_all = jnp.stack(req_ids)  # [K, 2, B]
         l7_cols, allowed_cols = [], []
+        decided_all = jnp.zeros(len(L7_MATCHERS), jnp.uint32)
         for d, outs in enumerate((outs_i, outs_e)):
             shape = outs.proxy_port.shape  # [K, B]
             rid = rid_all[:, d].reshape(-1)
             red = outs.proxy_port.reshape(-1) > 0
             flagged = red & requests["overflow"][rid]
+            decided = jnp.zeros(len(L7_MATCHERS), jnp.uint32)
             if live[d]:
                 ep = flow_batch_from_packed4(
                     jnp.moveaxis(pairs[:, d], 1, 0)
                 ).ep_index.reshape(-1)
                 slot = outs.l4_slot.reshape(-1).astype(jnp.int32)
                 ident = outs.sec_id.reshape(-1).astype(jnp.int32)
-                ok = _l7_direction(fl, requests, chunk, ep, d, slot,
-                                   ident, rid)
+                ok, decided = _l7_direction(fl, requests, chunk, ep, d,
+                                            slot, ident, rid, red)
                 if "wide" in requests:
                     ok = _redecide(fl, requests["wide"], chunk, ep, d,
                                    slot, ident, requests["wide_row"][rid],
@@ -481,11 +547,13 @@ def fleet_l7_program(fleet: FleetL7, chunk: int = L7_CHUNK):
                 jnp.sum(x, dtype=jnp.uint32)
                 for x in (red, l7, red & ~l7, flagged)
             ])
+            decided_all = decided_all + decided
             l7_cols.append(l7.reshape(shape))
             allowed_cols.append(allowed.reshape(shape))
         return L7Verdicts(
             jnp.stack(l7_cols, axis=1).astype(jnp.uint8),
             jnp.stack(allowed_cols, axis=1).astype(jnp.uint8),
+            decided_all,
         ), counts
 
     return jax.jit(l7_program, donate_argnums=(5,)), args
@@ -494,7 +562,12 @@ def fleet_l7_program(fleet: FleetL7, chunk: int = L7_CHUNK):
 class L7Stage:
     """What PersistentPairDispatcher(l7=...) runs after the fused
     program: the compiled fleet's tables and the request table on the
-    device, and the jitted L7 program (fleet_l7_program)."""
+    device, and the jitted L7 program (fleet_l7_program), whose HTTP
+    matcher decides only the redirected tuples of HTTP scopes and its
+    Kafka matcher only those of Kafka scopes.  Its counts are
+    L7_COUNTS (received, forwarded, denied, overflow); each call's
+    verdicts also carry the tuples each matcher decided
+    (L7Verdicts.decided, in L7_MATCHERS order)."""
 
     def __init__(self, fleet: FleetL7, requests: Dict[str, object],
                  chunk: int = L7_CHUNK) -> None:
@@ -514,10 +587,17 @@ class L7Stage:
 
         return jax.device_put(np.zeros(len(L7_COUNTS), np.uint32))
 
-    def fold_counts(self, counts) -> None:
+    def fold_counts(self, counts, decided=()) -> None:
         """Add drained counts (L7_COUNTS order) to
-        metrics.policy_l7_total{rule} and `overflowed`."""
+        metrics.policy_l7_total{rule} and `overflowed`, and the calls'
+        `decided` tallies (L7_MATCHERS order) to
+        metrics.policy_l7_matcher_tuples_total{parser}."""
         counts = np.asarray(counts)
         for rule, n in zip(L7_COUNTS[:3], counts):
             metrics.policy_l7_total.inc(rule, value=int(n))
         self.overflowed += int(counts[3])
+        total = np.zeros(len(L7_MATCHERS), np.int64)
+        for tally in decided:
+            total += np.asarray(tally)
+        for parser, n in zip(L7_MATCHERS, total):
+            metrics.policy_l7_matcher_tuples_total.inc(parser, value=int(n))

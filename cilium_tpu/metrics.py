@@ -237,6 +237,12 @@ class Registry:
             "Number of total L7 requests/responses",
             ("rule",),  # received|forwarded|denied|parse_errors
         )
+        self.policy_l7_matcher_tuples_total = Counter(
+            f"{ns}_policy_l7_matcher_tuples_total",
+            "Redirected tuples each fleet L7 matcher decided on the "
+            "persistent launch path, by parser",
+            ("parser",),  # http|kafka
+        )
         self.drop_count = Counter(
             f"{ns}_drop_count_total",
             "Total dropped packets by reason and direction",

@@ -5,13 +5,16 @@ the host matchers over the compiled fleet's rules of its scope (HTTP
 with method, path, Host and exact or presence headers; Kafka), a
 scope with no parser denies, a request over the field budgets is
 flagged and decided exactly all the same, the counts equal the fold
-of the verdicts and reach metrics.policy_l7_total at flush, a second
-launch compiles nothing, and without `l7` the dispatcher is what it
-was."""
+of the verdicts and reach metrics.policy_l7_total and
+metrics.policy_l7_matcher_tuples_total at flush, the matchers run
+over their own parser's tuples alone and decide what an evaluation
+of every tuple by both matchers decides, a second launch compiles
+nothing, and without `l7` the dispatcher is what it was."""
 
 import ipaddress
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -33,11 +36,15 @@ from cilium_tpu.ipcache.ipcache import IPIdentity
 from cilium_tpu.ipcache.lpm import specialize_ipcache_to_idx
 from cilium_tpu.l7 import memcached as mc
 from cilium_tpu.l7.fleet import (
+    _HTTP_COLS,
+    _KAFKA_COLS,
     L7_COUNTS,
+    L7_MATCHERS,
     PARSER_HTTP_ID,
     PARSER_KAFKA_ID,
     L7Stage,
     compile_fleet_l7,
+    evaluate_fleet_l7,
     pack_requests,
 )
 from cilium_tpu.l7.http import http_rule_matches_host
@@ -186,6 +193,7 @@ REQUESTS = [
 ]
 OVER_BUDGET = {11, 12, 18}
 HTTP_REQUESTS = {i for i, r in enumerate(REQUESTS) if r[2] is NO_KAFKA}
+KAFKA_REQUESTS = set(range(len(REQUESTS))) - HTTP_REQUESTS
 
 
 def request_table(fleet):
@@ -199,30 +207,36 @@ def _stage(fleet):
     return L7Stage(fleet, request_table(fleet))
 
 
+def _pair(world, rng, app, peer):
+    """One [2, 4, HALF] pair: tuple t ingress to endpoint app[t]'s L7
+    port from peers[peer[t]] (and egress back)."""
+    _, _, index, _, peers = world
+    pair = np.empty((2, 4, HALF), np.uint32)
+    ep_ip = np.asarray(
+        [int(ipaddress.ip_address(EPS[a][1])) for a in app], np.uint32
+    )
+    peer_ip = np.asarray([peers[p][0] for p in peer], np.uint32)
+    for d in (0, 1):
+        pair[d] = pack_flow_records4(
+            ep_index=[index[EPS[a][0]] for a in app],
+            saddr=peer_ip if d == 0 else ep_ip,
+            daddr=ep_ip if d == 0 else peer_ip,
+            sport=rng.integers(1024, 65535, HALF),
+            dport=[EPS[a][2] for a in app],
+            proto=np.full(HALF, 6), direction=np.full(HALF, d),
+        )
+    return pair
+
+
 def _traffic(world, rng, n_pairs):
     """n_pairs [2, 4, HALF] pairs, ingress to the three L7 ports from
     the six peers (and egress back), with their request-id planes."""
-    _, _, index, _, peers = world
     apps = list(EPS)
     pairs, reqs = [], []
     for _ in range(n_pairs):
-        pair = np.empty((2, 4, HALF), np.uint32)
         app = [apps[i] for i in rng.integers(0, 3, HALF)]
-        peer = rng.integers(0, len(peers), HALF)
-        ep_ip = np.asarray(
-            [int(ipaddress.ip_address(EPS[a][1])) for a in app], np.uint32
-        )
-        peer_ip = np.asarray([peers[p][0] for p in peer], np.uint32)
-        for d in (0, 1):
-            pair[d] = pack_flow_records4(
-                ep_index=[index[EPS[a][0]] for a in app],
-                saddr=peer_ip if d == 0 else ep_ip,
-                daddr=ep_ip if d == 0 else peer_ip,
-                sport=rng.integers(1024, 65535, HALF),
-                dport=[EPS[a][2] for a in app],
-                proto=np.full(HALF, 6), direction=np.full(HALF, d),
-            )
-        pairs.append(pair)
+        peer = rng.integers(0, len(world[4]), HALF)
+        pairs.append(_pair(world, rng, app, peer))
         reqs.append(rng.integers(0, len(REQUESTS), (2, HALF)).astype(
             np.uint32))
     return pairs, reqs
@@ -286,7 +300,8 @@ def test_l7_verdicts_equal_host_matchers(world):
         got.extend(disp.submit(pair, req))
         ref.extend(plain.submit(pair))
     counts = np.asarray(disp.l7_counts).astype(np.int64)
-    want_counts = np.zeros(4, np.int64)
+    want_counts = np.zeros(len(L7_COUNTS), np.int64)
+    want_decided = np.zeros(len(L7_MATCHERS), np.int64)
     cases = {"http": set(), "kafka": set(), "none": set(),
              "flagged": set()}
     mc_slot = int(d.endpoint_manager.published()[1].port_slot[6, 11211])
@@ -305,19 +320,25 @@ def test_l7_verdicts_equal_host_matchers(world):
             r = np.asarray(out.proxy_port) > 0
             np.testing.assert_array_equal(
                 allowed[dirn].astype(bool), base & (~r | l7_allowed[dirn]))
-        want_counts += [red.sum(), want.sum(), (red & ~want).sum(),
-                        flagged.sum()]
         slot = np.asarray(oi.l4_slot)
+        decided = {"http": 0, "kafka": 0, "none": 0}
         for t in np.nonzero(red)[0]:
             kind = fleet.parser_kind[int(pair[0][3][t] >> 16), 0, slot[t]]
             name = {PARSER_HTTP_ID: "http", PARSER_KAFKA_ID: "kafka"}.get(
                 int(kind), "none")
+            decided[name] += 1
             cases[name].add((int(req[0][t]), bool(want[t])))
             if name == "none":
                 assert slot[t] == mc_slot and not l7_allowed[0][t]
         cases["flagged"] |= {(int(req[0][t]), bool(want[t]))
                              for t in np.nonzero(flagged)[0]}
+        want_counts += [red.sum(), want.sum(), (red & ~want).sum(),
+                        flagged.sum()]
+        # each matcher decided exactly the redirected tuples of its
+        # parser's scopes (a count of tuples, not of chunk slots)
+        want_decided += [decided[m] for m in L7_MATCHERS]
     np.testing.assert_array_equal(counts, want_counts)
+    np.testing.assert_array_equal(_tally(disp), want_decided)
     # the traffic reached every case the test names
     http_seen = {r for r, _ in cases["http"]}
     assert HTTP_REQUESTS <= http_seen, sorted(http_seen)
@@ -330,11 +351,25 @@ def test_l7_verdicts_equal_host_matchers(world):
     assert {True, False} <= {ok for _, ok in cases["flagged"]}
 
 
+def _matcher_tuples():
+    return np.asarray([metrics.policy_l7_matcher_tuples_total.get(p)
+                       for p in L7_MATCHERS])
+
+
+def _tally(disp):
+    """The tuples each matcher decided since the last flush (the sum
+    of the L7 program's per-call `decided`, L7_MATCHERS order)."""
+    return sum((np.asarray(d).astype(np.int64) for d in disp.l7_decided),
+               np.zeros(len(L7_MATCHERS), np.int64))
+
+
 def test_counts_fold_into_metrics_at_flush(world):
     """flush() moves policy_l7_total{rule} by the drained received,
-    forwarded and denied counts and the stage's `overflowed` by the
-    drained overflow count (a staged remainder pair included, through
-    the L7 program), and restarts the device counts from zero."""
+    forwarded and denied counts, the stage's `overflowed` by the
+    drained overflow count and policy_l7_matcher_tuples_total{parser}
+    by the tuples each matcher decided (a staged remainder pair
+    included, through the L7 program), and restarts the device counts
+    and the matcher tally from zero."""
     _, tables, _, fleet, _ = world
     pairs, reqs = _traffic(world, np.random.default_rng(4), 3)
     stage = _stage(fleet)
@@ -343,8 +378,14 @@ def test_counts_fold_into_metrics_at_flush(world):
     for pair, req in zip(pairs, reqs):
         outs.extend(disp.submit(pair, req))
     drained = np.asarray(disp.l7_counts).astype(np.int64)
+    assert drained.shape == (len(L7_COUNTS),)
     assert drained[3] > 0
+    tally = _tally(disp)
+    assert (tally > 0).all()
+    # every tuple an HTTP or Kafka matcher decided was received
+    assert tally.sum() <= drained[0]
     before = [metrics.policy_l7_total.get(r) for r in L7_COUNTS[:3]]
+    matcher_before = _matcher_tuples()
     rest, _, _ = disp.flush()
     assert len(outs) == 2 and len(rest) == 1 and len(rest[0]) == 3
     red = np.asarray(rest[0][0].proxy_port) > 0
@@ -358,7 +399,143 @@ def test_counts_fold_into_metrics_at_flush(world):
     flagged = red & np.isin(reqs[2][0],
                             sorted(OVER_BUDGET))
     assert stage.overflowed == drained[3] + flagged.sum()
+    kind = fleet.parser_kind[
+        pairs[2][0][3] >> 16, 0,
+        np.minimum(rest[0][0].l4_slot, fleet.parser_kind.shape[2] - 1)]
+    np.testing.assert_array_equal(
+        _matcher_tuples() - matcher_before,
+        tally + [(red & (kind == PARSER_HTTP_ID)).sum(),
+                 (red & (kind == PARSER_KAFKA_ID)).sum()])
     assert int(np.asarray(disp.l7_counts).sum()) == 0
+    assert disp.l7_decided == []
+
+
+# the matchers' loops run in steps of CHUNK tuples in the equality
+# cases below, so a class of 16 or 17 tuples takes one or two steps
+CHUNK = 16
+
+
+@pytest.fixture(scope="module")
+def chunked(world):
+    """An L7 stage stepping CHUNK tuples at a time, and its request
+    table on the host."""
+    fleet = world[3]
+    table = request_table(fleet)
+    return L7Stage(fleet, table, chunk=CHUNK), table
+
+
+def _every_tuple(fleet, table, pair, out, req):
+    """(L7 verdict, final verdict, counts) of one direction of a
+    pair as the L7 program decided before its matchers were split by
+    parser: both matchers over every tuple at once, then each flagged
+    tuple again from the request table's wide columns."""
+    n = req.shape[0]
+    red = np.asarray(out.proxy_port) > 0
+    ep = jnp.asarray(pair[3] >> 16, jnp.int32)
+    slot = jnp.asarray(out.l4_slot, jnp.int32)
+    ident = jnp.asarray(out.sec_id, jnp.int32)
+
+    def decide(cols, rows):
+        return np.asarray(evaluate_fleet_l7(
+            fleet, ep, jnp.zeros(n, jnp.int32), slot, ident,
+            jnp.ones(n, bool),
+            http_fields=tuple(jnp.asarray(cols[k])[rows]
+                              for k in _HTTP_COLS),
+            kafka_fields=tuple(jnp.asarray(cols[k])[rows]
+                               for k in _KAFKA_COLS),
+            http_headers=(jnp.asarray(cols["hname"])[rows],
+                          jnp.asarray(cols["hpair"])[rows]),
+        ))
+
+    ok = decide(table, req)
+    flagged = red & table["overflow"][req]
+    if "wide" in table:
+        ok = np.where(flagged, decide(table["wide"], table["wide_row"][req]),
+                      ok)
+    l7 = red & ok
+    allowed = np.asarray(out.allowed).astype(bool) & (~red | l7)
+    return l7, allowed, [red.sum(), l7.sum(), (red & ~l7).sum(),
+                         flagged.sum()]
+
+
+ALPHA = (0, 2, 4)  # peers of the team every endpoint's rules allow
+BETA = (1, 3, 5)  # no rule of the cache endpoint allows this team
+# (web, kafka, cache from alpha, cache from beta) tuples of a pair, each
+# case's premise on its class counts (HTTP, Kafka, redirected with no
+# parser), and whether it carries requests over the field budgets
+MIXES = {
+    "no_http": ((0, 40, 12, 12), (0, 40, 12), False),
+    "no_kafka": ((40, 0, 12, 12), (40, 0, 12), False),
+    "all_redirected": ((28, 28, 8, 0), (28, 28, 8), False),
+    "http_kafka_one_chunk": ((CHUNK, CHUNK, 16, 16), (16, 16, 16), False),
+    "http_kafka_chunk_plus_one": ((CHUNK + 1, CHUNK + 1, 15, 15),
+                                  (17, 17, 15), False),
+    "no_parser_only": ((0, 0, 48, 16), (0, 0, 48), False),
+    "over_budget": ((24, 24, 8, 8), (24, 24, 8), True),
+}
+
+
+@pytest.mark.parametrize("mix", list(MIXES))
+def test_matchers_by_parser_equal_every_tuple(world, chunked, mix):
+    """The L7 program, whose HTTP and Kafka matchers each step only
+    over the redirected tuples of their parser's scopes (CHUNK at a
+    time), gives the L7 verdicts, final verdicts and counts that both
+    matchers over every tuple give, for a pair with
+    no HTTP tuple, with no Kafka tuple, with every tuple redirected,
+    with classes of exactly one chunk and of one chunk and one tuple,
+    with only redirects to a scope with no parser (all denied), and
+    with requests over the field budgets (the wide pass).
+    The matcher tally (L7Verdicts.decided) is the class counts:
+    tuples, not chunk slots."""
+    _, tables, _, fleet, _ = world
+    stage, table = chunked
+    (web, kafka, cache_a, cache_b), premise, over = MIXES[mix]
+    rng = np.random.default_rng(list(MIXES).index(mix))
+    app = (["web"] * web + ["kafka"] * kafka
+           + ["cache"] * (cache_a + cache_b))
+    peer = np.concatenate([
+        rng.choice(6, web + kafka), rng.choice(ALPHA, cache_a),
+        rng.choice(BETA, cache_b)]).astype(np.int64)
+    shuffle = rng.permutation(HALF)
+    app, peer = [app[i] for i in shuffle], peer[shuffle]
+    pool = set(range(len(REQUESTS))) - (set() if over else OVER_BUDGET)
+    kafka_ids = sorted(KAFKA_REQUESTS & pool)
+    http_ids = sorted(HTTP_REQUESTS & pool)
+    req = np.asarray([
+        [rng.choice(kafka_ids if a == "kafka" else http_ids) for a in app],
+        rng.choice(sorted(pool), HALF),
+    ], np.uint32)
+    pair = _pair(world, rng, app, peer)
+    disp = PersistentPairDispatcher(tables, 1, *_carry(tables), l7=stage)
+    (oi, oe, l7v), = disp.submit(pair, req)
+    counts = np.asarray(disp.l7_counts).astype(np.int64)
+
+    l7, allowed, want4 = _every_tuple(fleet, table, pair[0], oi, req[0])
+    np.testing.assert_array_equal(np.asarray(l7v.l7_allowed[0]), l7)
+    np.testing.assert_array_equal(np.asarray(l7v.allowed[0]), allowed)
+    np.testing.assert_array_equal(counts, want4)
+    # egress: nothing redirected, the L3/L4 verdict
+    assert not np.asarray(l7v.l7_allowed[1]).any()
+    np.testing.assert_array_equal(np.asarray(l7v.allowed[1]),
+                                  np.asarray(oe.allowed))
+
+    red = np.asarray(oi.proxy_port) > 0
+    kind = fleet.parser_kind[
+        pair[0][3] >> 16, 0,
+        np.minimum(oi.l4_slot, fleet.parser_kind.shape[2] - 1)]
+    classes = [int((red & (kind == k)).sum())
+               for k in (PARSER_HTTP_ID, PARSER_KAFKA_ID, 0)]
+    assert classes == list(premise)
+    np.testing.assert_array_equal(_tally(disp), classes[:2])
+    # a redirect with no parser is denied
+    assert not l7[red & (kind == 0)].any()
+    if mix == "all_redirected":
+        assert red.all()
+    # the case decides some tuples each way, and the over-budget case
+    # takes the wide pass
+    if classes[0] + classes[1]:
+        assert l7.any() and (red & ~l7).any()
+    assert bool(want4[3]) == over
 
 
 def _spans(fn):
