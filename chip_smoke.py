@@ -31,7 +31,7 @@ VERDICT_COLUMNS = (
     "ct_create", "ct_delete", "l4_slot", "ipcache_miss",
 )
 SERVE_BATCHES = 8
-SEED = 7  # run_config5's world seed
+SEED = 7  # the config-5 world's seed
 
 
 class SmokeFailure(RuntimeError):
@@ -217,8 +217,9 @@ def served_phase(world) -> None:
 
 
 def headline_tables(world):
-    """The bench headline's tables (run_config5 --no-autotune): the
-    hot policy plane at the compiled pack width, sub-word layouts."""
+    """The fused-pair program's tables: the hot policy plane at the
+    compiled pack width, sub-word layouts (as benchmark/world.py's
+    headline_tables)."""
     from cilium_tpu.compiler.tables import split_hot
     from cilium_tpu.engine.datapath import (
         DatapathTables,
@@ -304,7 +305,7 @@ def fitted_batch(tables_dev, policy, batch: int, k: int, dev):
 
 
 def fused_phase(world, args, dev) -> None:
-    """Batch pairs through the fused programs at the bench headline
+    """Batch pairs through the fused programs at the config-5
     geometry: one persistent K-pair launch (PersistentPairDispatcher)
     and the per-pair program over the same pairs.  Pair 0's columns
     are checked against the composed host oracle on a sample; its
@@ -337,7 +338,7 @@ def fused_phase(world, args, dev) -> None:
         f"persist_pairs={k} subword={report}"
     )
 
-    # run_config5's loader: per-direction pool subsets, packed4 pairs
+    # the fused-pair loader: per-direction pool subsets, packed4 pairs
     host_pairs, picks = bench.pack_pool_pairs(
         pool, np.random.default_rng(SEED + 2), half, k
     )

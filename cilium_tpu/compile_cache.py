@@ -1,6 +1,6 @@
 """JAX's persistent compile cache for the entry points.
 
-chip_smoke.py, bench.py and the agent call `enable_compile_cache()`
+chip_smoke.py and the agent call `enable_compile_cache()`
 before their first compile.  Nothing calls it at import time, so the
 test suite compiles without a cache.
 """

@@ -257,7 +257,7 @@ def table_shardings(mesh: Mesh, tables, table_axis: str = TABLE_AXIS):
     """NamedShardings pytree for device_put / DeviceTableStore: the
     default rule table resolved against `mesh`.  Leaves whose sharded
     axis does not divide by the mesh's table-axis size fall back to
-    replicated (correctness first; tools/shardprof.py reports it)."""
+    replicated (correctness first; shard_bytes_model reports it)."""
     specs = divisible_partition_specs(
         tables, int(mesh.shape[table_axis]), table_axis
     )
@@ -455,8 +455,8 @@ def replica_bytes_model(tables, num_shards: int,
     cost 2/num_shards per chip (their own slice + the neighbour's
     backup copy), everything else as the plain sharded model.
     Returns (rows, per_chip_total, replica_overhead_per_chip) where
-    the overhead is exactly the backup copies' bytes — the quantity
-    tools/shardprof.py bounds by sharded_bytes / num_shards."""
+    the overhead is exactly the backup copies' bytes, bounded by
+    sharded_bytes / num_shards."""
     axes = replica_axes(tables, num_shards, table_axis)
     rows, per_chip, _replicated = shard_bytes_model(
         tables, num_shards, table_axis
@@ -523,7 +523,7 @@ def universe_max_identities(
     count N and divide across `num_shards`; replicated leaves are a
     per-chip constant.  Solving
         replicated + identity_bytes_per_id * N / num_shards ≤ hbm
-    for N gives the `universe_max_identities` line bench emits — the
+    for N gives the universe's max identities — the
     capacity the sharding refactor actually buys (the replicated
     layout is the num_shards=1 row).
 

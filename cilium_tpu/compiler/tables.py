@@ -146,9 +146,10 @@ def trim_stash(stash: np.ndarray) -> np.ndarray:
 # (16 entries) where the 3-word layout needs 64 lanes: the dominant
 # lattice gathers halve again.  The probe reconstructs proxy with ONE
 # l4_meta element gather at the combined j (+4 B/tuple, priced by
-# gatherprof).  Semantics allow it only when idx < 2^18-1 (universe
-# ≤ 262142 padded identities), ep < 2^8, j < 2^12 — repack_l4_subword
-# verifies and refuses otherwise.  The stash ships 2-word entries too:
+# autotune.hot_bytes_per_tuple).  Semantics allow it only when
+# idx < 2^18-1 (universe ≤ 262142 padded identities), ep < 2^8,
+# j < 2^12 — repack_l4_subword verifies and refuses otherwise.  The
+# stash ships 2-word entries too:
 # its width (2 vs 3) is the LAYOUT MARKER the kernels branch on
 # (l4_entry_words: a static jit-cache axis that travels with the
 # pytree, no aux-structure change).

@@ -313,7 +313,7 @@ class Daemon:
         self.online_retune_config: Dict = {}
         self._retune_inflight = threading.Lock()
         # fused-tables byte model cache for perf_snapshot: keyed by
-        # (generation, layout) so the gatherprof walk runs once per
+        # (generation, layout) so the byte-model walk runs once per
         # publish, not per /debug/perf poll
         self._perf_model_cache = None
         # per-tenant named SLO classes (serving tier 2): name ->
@@ -2350,7 +2350,7 @@ class Daemon:
             self._retune_inflight.release()
 
     def _perf_byte_model(self, leaves: bool = False) -> Dict:
-        """The gatherprof/autotune byte model evaluated LIVE: the
+        """The autotune byte model evaluated LIVE: the
         published layout stamp's hot/cold bytes-per-tuple, shrunk by
         the OBSERVED verdict-cache dedup/hit factors, and priced
         into a modeled GB/s gauge at the perf plane's measured

@@ -20,7 +20,7 @@ storms would otherwise show up in the existing
 `cilium_jit_cache_*{site}` metrics this module deliberately rides.
 
 `hot_gather_profile` is the bytes-moved model behind the tuner and
-the bench's `hot_bytes_per_tuple` line: per-leaf bytes GATHERED per
+`hot_bytes_per_tuple`: per-leaf bytes GATHERED per
 tuple by the fused per-direction pipeline, split into the hot plane
 (leaves the hashed-probe kernels actually gather) and the cold plane
 (dense-fallback leaves a hot-only publication never ships).
@@ -351,8 +351,7 @@ def memo_candidates(
     headroom_frac: float = 0.25,
     rows_cap: Optional[int] = None,
 ) -> List[dict]:
-    """Verdict-memoization candidates for the tuner (the schema
-    bench's `_run_memo_candidate` consumes): cache row counts ×
+    """Verdict-memoization candidates for the tuner: cache row counts ×
     rep/miss compaction capacities (batch >> shift, so the lattice
     gather chain shrinks when the workload's key skew covers it).
     `{"memo": False}` is the ENABLE THRESHOLD: when the sort+probe
@@ -519,7 +518,7 @@ def retune_candidates(daemon, plane):
     capacity (HBM-aware via the store's chip_bytes seam), and the
     fused plane's CT / ipcache hot-lane widths (the
     subword_datapath_tables ct_lanes seam + the ipcache sub-word
-    toggle), all scored by the same gatherprof byte model."""
+    toggle), all scored by the same hot_bytes_per_tuple byte model."""
     batch = plane.batch_size if plane is not None else 1 << 12
     batches = sorted(
         {max(batch // 2, 256), batch, min(batch * 2, 1 << 15)}
@@ -558,11 +557,11 @@ def retune_candidates(daemon, plane):
 
 def _model_run_candidate(daemon, plane):
     """Default candidate scorer when no measured `run_candidate` is
-    supplied: rank by the gatherprof byte model at each candidate's
+    supplied: rank by the hot_bytes_per_tuple byte model at each candidate's
     pack width, scaled by the plane's measured verdicts/s EWMA —
     deterministic and sweep-free, so the serve loop never pays a
     device measurement campaign mid-stream.  Callers wanting a
-    MEASURED sweep (bench) pass their own run_candidate."""
+    MEASURED sweep pass their own run_candidate."""
     _, tables, _ = daemon.endpoint_manager.published()
     base_vps = max(daemon.perf.verdicts_per_sec(), 1.0)
     base_lanes = daemon.endpoint_manager._fleet_compiler.hash_lanes
@@ -770,14 +769,13 @@ def tables_layout_stamp(tables) -> Optional[int]:
 def effective_hot_bytes_per_tuple(
     tables, dedup_factor: float, packed_io: bool = True
 ) -> float:
-    """The gather-byte model under intra-batch dedup: gatherprof's
+    """The gather-byte model under intra-batch dedup:
     hot_bytes_per_tuple divided by the measured dedup factor — the
     bytes the lattice ACTUALLY moves per tuple once duplicates
     collapse onto one representative.  Cache hits shrink it further
     (a hit gathers one cache row instead of the lattice rows); this
-    line deliberately prices only the dedup level so the bench's
-    `effective_verdicts_per_sec_per_chip` stays the measured truth
-    and the model stays conservative."""
+    line deliberately prices only the dedup level so the model stays
+    conservative."""
     return hot_bytes_per_tuple(tables, packed_io=packed_io) / max(
         float(dedup_factor), 1.0
     )

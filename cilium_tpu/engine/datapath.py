@@ -202,7 +202,7 @@ FLOW_COLUMNS = (
 #   row 2  sport << 16 | dport
 #   row 3  ep_index << 16 | proto << 8 | direction << 1 | is_fragment
 # The unpack runs INSIDE the jitted program (host-visible semantics
-# unchanged — bit-identity gated in bench and tests); ranges are the
+# unchanged — bit-identity gated in the tests); ranges are the
 # same invariants the tables already rely on (ports < 2^16, proto <
 # 2^8, ep_index < 2^16 per the hashed-key endpoint cap).
 def pack_flow_records4(
@@ -658,67 +658,6 @@ datapath_step_with_counters = jax.jit(_datapath_kernel_with_counters)
 datapath_step_accum = jax.jit(_datapath_kernel_accum, donate_argnums=(2,))
 
 
-def _accum_dir_kernel(direction):
-    def kernel(tables, flows, acc):
-        return _datapath_core(
-            tables,
-            flows,
-            with_counters=True,
-            acc=acc,
-            emit_sec_id=False,
-            static_direction=direction,
-        )
-
-    return kernel
-
-
-# direction-specialized streaming programs (bpf_lxc's separate
-# ingress/egress sections): callers that split their flow stream per
-# direction — as the kernel datapath inherently does — dispatch these
-datapath_step_accum_ingress = jax.jit(
-    _accum_dir_kernel(INGRESS), donate_argnums=(2,)
-)
-datapath_step_accum_egress = jax.jit(
-    _accum_dir_kernel(EGRESS), donate_argnums=(2,)
-)
-
-
-def _datapath_kernel_accum_pair(tables, flows_in, flows_eg, acc):
-    """BOTH direction-specialized programs in ONE dispatch, with the
-    two batches' counter hits concatenated into a SINGLE scatter.
-    Per pair of half-batches this saves one dispatch floor and one
-    scatter relative to alternating the per-direction programs —
-    a measurable slice of the headline loop on v5e — while computing
-    bit-identical verdicts and counters (scatter-adds commute)."""
-    from cilium_tpu.engine.verdict import _counter_cols
-
-    out_i, (v_i, res_i, j_i, idx_i) = _datapath_core(
-        tables, flows_in, with_counters=True, emit_sec_id=False,
-        static_direction=INGRESS, defer_counters=True,
-    )
-    out_e, (v_e, res_e, j_e, idx_e) = _datapath_core(
-        tables, flows_eg, with_counters=True, emit_sec_id=False,
-        static_direction=EGRESS, defer_counters=True,
-    )
-    with jax.named_scope("accounting"):
-        kg = tables.policy.l4_meta.shape[2]
-        ep_i, d_i, c_i, w_i = _counter_cols(v_i, res_i, j_i, idx_i, kg)
-        ep_e, d_e, c_e, w_e = _counter_cols(v_e, res_e, j_e, idx_e, kg)
-        acc = acc.at[
-            jnp.concatenate([ep_i, ep_e]),
-            jnp.concatenate([d_i, d_e]),
-            jnp.concatenate([c_i, c_e]),
-        ].add(jnp.concatenate([w_i, w_e]))
-    return out_i, out_e, acc
-
-
-# the headline streaming shape: one dispatch evaluates an ingress
-# half-batch AND an egress half-batch with one merged counter scatter
-datapath_step_accum_pair = jax.jit(
-    _datapath_kernel_accum_pair, donate_argnums=(3,)
-)
-
-
 def _datapath_kernel_telem(tables: DatapathTables, flows: FlowBatch):
     """One-shot instrumented step: full verdicts + this batch's
     [2, TELEM_COLS] stage histogram (tests, trace tooling, smoke)."""
@@ -744,12 +683,13 @@ def _datapath_kernel_accum_telem(
 def _datapath_kernel_accum_pair_telem(
     tables, flows_in, flows_eg, acc, telem
 ):
-    """The instrumented headline shape: the paired-dispatch program
-    (one dispatch, one merged counter scatter per direction pair)
-    plus per-direction stage accounting folded into the carried
-    telemetry buffer — bit-identical verdicts and counters to
-    datapath_step_accum_pair, with the [2, TELEM_COLS] reductions
-    fused into the same program."""
+    """The one fused-pair body: an ingress and an egress half-batch,
+    each through the direction-specialized core (bpf_lxc's separate
+    ingress/egress sections), in ONE program.  Both halves' counter
+    hits go into a SINGLE concatenated scatter, and their
+    [2, TELEM_COLS] stage histograms fold into the carried telemetry
+    buffer — verdicts and counters bit-identical to two sequential
+    datapath_step_accum calls (scatter-adds commute)."""
     from cilium_tpu.engine.verdict import _counter_cols
 
     out_i, (v_i, res_i, j_i, idx_i), trow_i = _datapath_core(
@@ -779,19 +719,16 @@ datapath_step_telem = jax.jit(_datapath_kernel_telem)
 datapath_step_accum_telem = jax.jit(
     _datapath_kernel_accum_telem, donate_argnums=(2, 3)
 )
-datapath_step_accum_pair_telem = jax.jit(
-    _datapath_kernel_accum_pair_telem, donate_argnums=(3, 4)
-)
 
 
 def _datapath_kernel_accum_pair_telem_packed4(
     tables, packed_in, packed_eg, acc, telem
 ):
-    """The async-dispatch headline shape: both half-batches arrive in
-    the packed4 staging format ([4, B] u32, 16 B/tuple H2D) and
-    unpack INSIDE the fused program — bit-identical verdicts,
-    counters and telemetry to datapath_step_accum_pair_telem over the
-    same flows (the unpack is exact; bench gates it)."""
+    """The pair body over the packed4 staging format: both
+    half-batches arrive as [4, B] u32 (16 B/tuple H2D) and unpack
+    INSIDE the fused program.  The unpack is exact, so verdicts,
+    counters and telemetry are those of the pair body over the same
+    flows."""
     return _datapath_kernel_accum_pair_telem(
         tables,
         flow_batch_from_packed4(packed_in),
@@ -799,11 +736,6 @@ def _datapath_kernel_accum_pair_telem_packed4(
         acc,
         telem,
     )
-
-
-datapath_step_accum_pair_telem_packed4 = jax.jit(
-    _datapath_kernel_accum_pair_telem_packed4, donate_argnums=(3, 4)
-)
 
 
 def _datapath_kernel_accum_pair_telem_packed4_stacked(
@@ -843,8 +775,8 @@ def subword_datapath_tables(
     Each plane transforms independently; one whose ranges don't fit
     its compact fields keeps its wide layout (or raises when
     `strict`).  Returns (tables, report) — report maps plane ->
-    "packed"/"kept: <why>" so bench/gatherprof can emit the
-    per-width model honestly.  Verdicts are bit-identical by
+    "packed"/"kept: <why>" so a caller can report the per-width
+    model honestly.  Verdicts are bit-identical by
     construction (each transform's contract), and every changed
     plane moves the layout stamp (datapath_layout_version) so
     delta publication refuses across the seam."""
@@ -925,9 +857,9 @@ def datapath_layout_version(dtables: DatapathTables) -> tuple:
 # ---------------------------------------------------------------------------
 # Persistent fused-pair program: zero per-pair dispatch
 # ---------------------------------------------------------------------------
-# The headline loop's remaining host cost is the PER-PAIR dispatch
-# floor: one launch + one drain round trip per pair batch, which the
-# async overlap hides only partially (the host still touches the
+# A loop of one launch per pair pays a PER-PAIR dispatch floor: one
+# launch + one drain round trip per pair batch, which the async
+# overlap hides only partially (the host still touches the
 # executable K times).  The persistent program evaluates K staged
 # pairs in ONE launch: a lax.scan walks the [K, 2, 4, B] super-batch,
 # the counter/telemetry carry is donated device-resident state woven
@@ -998,7 +930,7 @@ class PersistentPairDispatcher:
     final (results, acc, telem).
 
     The jit-tracking proof rides `site`: wrap-tracked launches land
-    in cilium_jit_cache_*{site} so a test (or the bench) can assert
+    in cilium_jit_cache_*{site} so a test (or a smoke) can assert
     K pairs cost exactly one executable call; the device stack is
     tracked at `site + ".stack"`.
 

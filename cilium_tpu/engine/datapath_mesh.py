@@ -611,7 +611,8 @@ def make_failover_datapath_evaluator(
     l3_counts [E, 2, N] GLOBAL (fold_l3_aug applied host-side),
     replica_hits u32 scalar [, per-chip telemetry [dp, 2, T]]) —
     bit-identical on the valid mask to the single-device fused
-    program (engine/datapath.datapath_step*) and the composed host
+    program (engine/datapath.datapath_step_with_counters) and the
+    composed host
     oracle, whatever the survivor set, as long as one owner of every
     slice is alive."""
     _check_fused_world(dtables)
@@ -695,7 +696,8 @@ def make_failover_datapath_pair_evaluator(
     half — the engine/datapath.py headline staging format carried
     onto the mesh), with the counters and telemetry riding the same
     dispatch.  The ingress program compiles with no LB/service-CT
-    stages at all, exactly like datapath_step_accum_ingress.
+    stages at all, exactly like the ingress half of
+    engine/datapath.py's pair program.
 
     Returns run(dtables_aug, pair, alive, valid [2, B]) ->
     (out_ingress, out_egress, l4_counts, l3_counts (global),

@@ -7,8 +7,8 @@ states), evaluated per tuple in the same order the fused program
 fuses: prefilter → LB/DNAT with service-scope stickiness → conntrack
 → ipcache identity derivation → policy lattice → combine
 (bpf_lxc.c:440/899).  Device outputs must be BIT-IDENTICAL to this on
-any input — the bench's pre-timing gate, the multichip dryrun and the
-test suite all cross-check through it.
+any input — chip_smoke.py, the multichip dryrun and the test suite
+all cross-check through it.
 """
 
 from __future__ import annotations
@@ -21,7 +21,7 @@ import numpy as np
 
 class HostLPM:
     """Fast host-side LPM oracle: /32s in a dict, other prefixes
-    scanned longest-first (their count stays small in the bench
+    scanned longest-first (their count stays small in the config-5
     worlds, unlike the /32 population)."""
 
     def __init__(self, mapping: Dict[str, int]):

@@ -1130,8 +1130,7 @@ class AsyncBatchDispatcher:
     Overlap accounting: `pack_s` (host-side staging time), `block_s`
     (time spent blocked waiting on device results) and `wall_s`
     (first submit → flush) let callers derive the device-busy
-    fraction during sustained dispatch (bench's
-    overlap_efficiency_pct)."""
+    fraction during sustained dispatch."""
 
     def __init__(self, pack_fn, dispatch_fn, depth: int = 1) -> None:
         from collections import deque

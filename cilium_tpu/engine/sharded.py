@@ -386,8 +386,8 @@ def make_partitioned_evaluator(
         if rows_sharded:
             # return the verdict column to the originating shard:
             # the key lives in exactly one shard, so the sums are
-            # exact (this psum pair is the alltoall_bytes_per_tuple
-            # the bench models)
+            # exact (this psum pair is the all-to-all traffic per
+            # tuple of the row-sharded layout)
             val1 = jax.lax.psum(val_local, table_axis)
             found1 = (
                 jax.lax.psum(

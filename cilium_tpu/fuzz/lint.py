@@ -6,8 +6,8 @@ a legacy global-state numpy/stdlib random call) anywhere on a fuzz
 code path makes "reproducible from the logged seed alone" a lie.
 This module is the grep-able guarantee: a source-level scan for the
 unseeded idioms, run by tests over the fuzzer package and the seeded
-tooling (tools/policyfuzz.py, tools/chaos_storm.py, bench.py's
-zipf/pool samplers).
+tooling (tools/policyfuzz.py, tools/chaos_storm.py, and bench.py's
+config-5 world builders, Zipf and flow-pool samplers included).
 
 The scan is intentionally source-text based (not runtime): an
 unseeded call on a COLD path (an error branch, a rarely-taken event)

@@ -631,7 +631,7 @@ class Registry:
         )
         self.perf_model_bytes_per_tuple = Gauge(
             f"{ns}_perf_model_bytes_per_tuple",
-            "The gatherprof byte model evaluated LIVE against the "
+            "The autotune byte model evaluated LIVE against the "
             "published layout stamp: hot = modeled hot-plane gather "
             "bytes, cold = dense-fallback bytes, effective = hot "
             "under the observed dedup/cache-hit factors",

@@ -1,7 +1,8 @@
 """Live performance plane: continuous hot-path self-profiling.
 
 Every perf win since the hot/cold split is validated OFFLINE by
-bench.py and the gatherprof byte model; in production the agent was
+the benchmark and the byte model (engine/autotune.hot_bytes_per_tuple);
+in production the agent was
 blind to its own hot path.  This module is the always-on counterpart:
 a low-overhead observability layer riding the existing dispatch seams
 — nothing here adds a kernel, a sync, or a lock on the device path.
@@ -26,7 +27,7 @@ a low-overhead observability layer riding the existing dispatch seams
     burn > 1 means the tenant is eating budget faster than its class
     allows.
 
-  * **Live byte model.**  The gatherprof/autotune model evaluated
+  * **Live byte model.**  The autotune byte model evaluated
     against the PUBLISHED layout stamp and the OBSERVED cache-hit /
     dedup factors (Daemon.perf_snapshot assembles it): effective
     bytes-per-tuple and modeled GB/s as gauges, per-leaf breakdown on
@@ -39,9 +40,9 @@ a low-overhead observability layer riding the existing dispatch seams
 
 Everything windowed is exported to Prometheus at a bounded cadence
 (every `EXPORT_EVERY` batches + at snapshot time), and the plane
-accounts its OWN bookkeeping seconds (`overhead_s`) so bench's
-`perfplane_overhead_pct` gate is measured inside the instrumented
-loop, the tracing_overhead_pct discipline.
+accounts its OWN bookkeeping seconds (`overhead_s`) so its overhead
+is measured inside the instrumented loop, the tracing_overhead_pct
+discipline.
 
 Simulation boundary: on this container the "device" is XLA's CPU
 backend — absolute phase durations and modeled GB/s are only

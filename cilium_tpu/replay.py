@@ -908,8 +908,9 @@ def replay_pool(
     pool[picks] — only the loader changes: 4 bytes/tuple instead of
     decoding+packing+uploading 24-byte records.  `ct_map` is required: pool mode
     IS the churn loader (for churn-free pool replay, pre-stage device
-    batches as bench.run_config5's headline loop does).  Counter
-    accumulation is not offered here for the same reason.
+    batches as engine/datapath.PersistentPairDispatcher's callers
+    do).  Counter accumulation is not offered here for the same
+    reason.
     """
     import time
 

@@ -1532,7 +1532,7 @@ def run_serve_bench(
     — open loop, so queue delay is real.  `make_records(rng, n)`
     returns a decoded record SoA of n flows.
 
-    Returns the serving metrics the bench emits:
+    Returns the serving metrics:
     sustained_verdicts_per_sec, serving_p99_ms, queue-delay and
     batch-fill aggregates, and per-tenant admitted/shed counts."""
     rng = np.random.default_rng(seed)

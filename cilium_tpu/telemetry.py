@@ -1,7 +1,8 @@
 """Host half of the datapath telemetry plane.
 
 The instrumented device kernels (engine/datapath.py
-``datapath_step_*_telem``) carry a [2, TELEM_COLS] u32 stage/drop
+``datapath_step_telem``, ``datapath_step_accum_telem`` and the
+fused-pair programs) carry a [2, TELEM_COLS] u32 stage/drop
 accumulator alongside the per-entry counter buffer — one masked-sum
 reduction set fused into the verdict dispatch, no extra launches.
 This module folds that accumulator (or, equivalently, per-tuple
@@ -16,7 +17,7 @@ DatapathVerdicts columns host-side) into:
 Both folds derive from the ONE mask definition set
 (engine.verdict.telemetry_masks), so the on-device histogram and the
 host per-tuple fold are bit-identical by construction — the property
-the bench's telemetry gate asserts on a ≥1M-tuple batch.
+chip_smoke.py and tests/test_telemetry.py assert.
 """
 
 from __future__ import annotations
@@ -206,7 +207,7 @@ def telemetry_summary(telem) -> Dict[str, Dict[str, int]]:
 def telemetry_consistent(telem) -> bool:
     """Internal-consistency invariants of one histogram: the final
     outcomes partition the batch, and the drop columns partition the
-    denials.  The bench gate asserts this on the device buffer before
+    denials.  The tests assert this on the device buffer before
     comparing against the host fold."""
     telem = np.asarray(telem)
     ok = True

@@ -26,9 +26,9 @@ Determinism and cost are first-class: ids come from a seedable RNG
 (tests pin exact ids), sampling is HEAD-based (the decision is made
 once at the root — an unsampled request creates no spans at all, the
 same shape as the flow plane's head-sampled allows), and the tracer
-accounts its own bookkeeping time in `overhead_s` so bench.py's
-`tracing_overhead_pct` gate and tools/trace_smoke.py measure the real
-hot-path cost instead of an A/B of noisy wall clocks.
+accounts its own bookkeeping time in `overhead_s` so
+tools/trace_smoke.py measures the real hot-path cost instead of an A/B
+of noisy wall clocks.
 
 Join keys: the trace id is stamped into FlowRecords captured during a
 traced batch (GET /flows?trace-id=...) and the jit/table metrics are
@@ -200,7 +200,7 @@ class Tracer:
         self.finished_total = 0
         # the tracer's own bookkeeping seconds (begin/finish, ring
         # append) — what tracing actually charges the instrumented
-        # path; bench.py's tracing_overhead_pct reads this
+        # path; tools/trace_smoke.py reads this
         self.overhead_s = 0.0
 
     # -- id generation --------------------------------------------------------

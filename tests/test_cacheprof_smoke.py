@@ -1,7 +1,7 @@
 """tools/cacheprof.py as a tier-1 test: the Zipf hit-rate curve of
 the verdict-memo plane at smoke scale — dedup_factor >= 2 at s=1.1,
 zero hits across a publish boundary, effective hot-bytes dumped next
-to the raw gatherprof number (fast, not slow)."""
+to the raw hot_bytes_per_tuple number (fast, not slow)."""
 
 import json
 

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 B = 65_536  # tuples per direction
-K = 4  # the bench's --persist-pairs
+K = 4  # the benchmark's pairs per persistent launch
 
 
 @pytest.fixture(scope="module")

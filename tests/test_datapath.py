@@ -350,19 +350,67 @@ def test_idx_form_ipcache_matches_generic(seed):
         )
 
 
-@pytest.mark.parametrize("direction", [0, 1])
-def test_direction_specialized_kernels_match_generic(direction):
-    """The per-direction streaming programs (bpf_lxc's separate
-    ingress/egress sections) must agree with the generic kernel on
-    single-direction batches, counters included."""
+_VERDICT_FIELDS = (
+    "allowed", "proxy_port", "match_kind", "ct_result",
+    "pre_dropped", "sec_id", "final_daddr", "final_dport",
+    "rev_nat", "lb_slave", "ct_create", "ct_delete", "l4_slot",
+)
+
+
+def _pair_program(tables, flows_in, flows_eg):
+    """One pair through the fused-pair program, staged as the
+    dispatcher stages it: both halves packed4 into one [2, 4, B]."""
     import jax
 
     from cilium_tpu.engine.datapath import (
-        datapath_step_accum,
-        datapath_step_accum_egress,
-        datapath_step_accum_ingress,
+        datapath_step_accum_pair_telem_packed4_stacked,
+        pack_flow_records4,
     )
+    from cilium_tpu.engine.verdict import (
+        make_counter_buffers,
+        make_telemetry_buffers,
+    )
+
+    pair = np.stack([pack_flow_records4(**f) for f in (flows_in, flows_eg)])
+    return datapath_step_accum_pair_telem_packed4_stacked(
+        tables, pair,
+        jax.device_put(make_counter_buffers(tables.policy)),
+        jax.device_put(make_telemetry_buffers()),
+    )
+
+
+def _accum_in_sequence(tables, *flow_dicts):
+    """The generic streaming step over each batch in turn, one carried
+    counter buffer."""
+    import jax
+
+    from cilium_tpu.engine.datapath import datapath_step_accum
     from cilium_tpu.engine.verdict import make_counter_buffers
+
+    acc = jax.device_put(make_counter_buffers(tables.policy))
+    outs = []
+    for f in flow_dicts:
+        out, acc = datapath_step_accum(tables, FlowBatch.from_numpy(**f), acc)
+        outs.append(out)
+    return outs, acc
+
+
+def _assert_verdicts_equal(a, b):
+    for field in _VERDICT_FIELDS:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(a, field)),
+            np.asarray(getattr(b, field)),
+            err_msg=field,
+        )
+
+
+@pytest.mark.parametrize("direction", [0, 1])
+def test_direction_specialized_kernels_match_generic(direction):
+    """The pair program's direction-specialized halves (bpf_lxc's
+    separate ingress/egress sections) must agree with the generic
+    kernel on single-direction batches, counters included: the batch
+    of `direction` rides its own half, a batch of the other direction
+    the other."""
     from cilium_tpu.ipcache.lpm import specialize_ipcache_to_idx
 
     (rng, pf, ipc, ct, mgr, states, tables, n_eps) = _build_world(4)
@@ -375,79 +423,40 @@ def test_direction_specialized_kernels_match_generic(direction):
     )
     f = _random_flows(rng, 512, n_eps)
     f["direction"] = np.full(512, direction)
-    flows = FlowBatch.from_numpy(**f)
+    other = _random_flows(rng, 512, n_eps)
+    other["direction"] = np.full(512, 1 - direction)
+    halves = (f, other) if direction == 0 else (other, f)
 
-    acc_a = jax.device_put(make_counter_buffers(tables.policy))
-    a, acc_a = datapath_step_accum(tables, flows, acc_a)
-    acc_b = jax.device_put(make_counter_buffers(tables.policy))
-    fn = (
-        datapath_step_accum_ingress
-        if direction == 0
-        else datapath_step_accum_egress
-    )
-    b, acc_b = fn(tables, flows, acc_b)
-    for field in (
-        "allowed", "proxy_port", "match_kind", "ct_result",
-        "pre_dropped", "sec_id", "final_daddr", "final_dport",
-        "rev_nat", "lb_slave", "ct_create", "ct_delete",
-    ):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(a, field)),
-            np.asarray(getattr(b, field)),
-            err_msg=field,
-        )
+    (a, a_other), acc_a = _accum_in_sequence(tables, f, other)
+    out_i, out_e, acc_b, _ = _pair_program(tables, *halves)
+    b, b_other = (out_i, out_e) if direction == 0 else (out_e, out_i)
+    _assert_verdicts_equal(a, b)
+    _assert_verdicts_equal(a_other, b_other)
     np.testing.assert_array_equal(np.asarray(acc_a), np.asarray(acc_b))
 
 
 def test_paired_dispatch_matches_sequential():
     """The one-dispatch ingress+egress pair program must produce
-    bit-identical verdicts AND counters to running the two
-    direction-specialized programs sequentially."""
-    import jax
-
-    from cilium_tpu.engine.datapath import (
-        datapath_step_accum_egress,
-        datapath_step_accum_ingress,
-        datapath_step_accum_pair,
-    )
-    from cilium_tpu.engine.verdict import make_counter_buffers
-
+    bit-identical verdicts AND counters to running the generic
+    streaming step over the two half-batches sequentially."""
     (rng, _, _, ct, _, states, tables, n_eps) = _build_world(23)
     pool = _random_flows(rng, 256, n_eps)
     idx_in = np.nonzero(pool["direction"] == 0)[0]
     idx_eg = np.nonzero(pool["direction"] == 1)[0]
     half = 96
-    from cilium_tpu.engine.datapath import FlowBatch
 
     def batch_of(rows):
         picks = rows[rng.integers(0, len(rows), size=half)]
-        return FlowBatch.from_numpy(
-            **{k: pool[k][picks] for k in (
-                "ep_index", "saddr", "daddr", "sport", "dport",
-                "proto", "direction", "is_fragment",
-            )}
-        )
+        return {k: pool[k][picks] for k in (
+            "ep_index", "saddr", "daddr", "sport", "dport",
+            "proto", "direction", "is_fragment",
+        )}
 
     fin, feg = batch_of(idx_in), batch_of(idx_eg)
 
-    acc1 = make_counter_buffers(tables.policy)
-    oi1, acc1 = datapath_step_accum_ingress(tables, fin, acc1)
-    oe1, acc1 = datapath_step_accum_egress(tables, feg, acc1)
-
-    acc2 = make_counter_buffers(tables.policy)
-    oi2, oe2, acc2 = datapath_step_accum_pair(tables, fin, feg, acc2)
+    (oi1, oe1), acc1 = _accum_in_sequence(tables, fin, feg)
+    oi2, oe2, acc2, _ = _pair_program(tables, fin, feg)
 
     for a, b in ((oi1, oi2), (oe1, oe2)):
-        np.testing.assert_array_equal(
-            np.asarray(a.allowed), np.asarray(b.allowed)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(a.proxy_port), np.asarray(b.proxy_port)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(a.sec_id), np.asarray(b.sec_id)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(a.l4_slot), np.asarray(b.l4_slot)
-        )
+        _assert_verdicts_equal(a, b)
     np.testing.assert_array_equal(np.asarray(acc1), np.asarray(acc2))

@@ -17,7 +17,6 @@ from cilium_tpu.engine.verdict import (
     TELEM_MATCH_L4_WILD,
     TELEM_MATCH_NONE,
     TELEM_TOTAL,
-    make_telemetry_buffers,
 )
 from cilium_tpu.telemetry import (
     fold_telemetry,
@@ -53,19 +52,16 @@ def test_device_telemetry_matches_host_fold():
     assert int(got[:, TELEM_TOTAL].sum()) == len(f["direction"])
 
 
-def test_accum_pair_telem_bit_identical_to_bare_pair():
-    """The instrumented paired-dispatch program returns the same
-    verdicts AND counter scatter as the bare one; its telemetry
-    equals the host fold of its own outputs."""
-    import jax
-
-    from cilium_tpu.engine.datapath import (
-        FlowBatch,
-        datapath_step_accum_pair,
-        datapath_step_accum_pair_telem,
+def test_pair_program_matches_accum_and_telem_equals_host_fold():
+    """The fused-pair program returns the same verdicts AND counter
+    scatter as the generic streaming step over its two halves in
+    turn; its telemetry equals the host fold of its own outputs."""
+    from tests.test_datapath import (
+        _accum_in_sequence,
+        _build_world,
+        _pair_program,
+        _random_flows,
     )
-    from cilium_tpu.engine.verdict import make_counter_buffers
-    from tests.test_datapath import _build_world, _random_flows
 
     (rng, _, _, _, _, _, tables, n_eps) = _build_world(3)
     half = 256
@@ -73,16 +69,9 @@ def test_accum_pair_telem_bit_identical_to_bare_pair():
     f_in["direction"][:] = 0
     f_eg = _random_flows(rng, half, n_eps)
     f_eg["direction"][:] = 1
-    fin = FlowBatch.from_numpy(**f_in)
-    feg = FlowBatch.from_numpy(**f_eg)
 
-    acc1 = make_counter_buffers(tables.policy)
-    oi1, oe1, acc1 = datapath_step_accum_pair(tables, fin, feg, acc1)
-    acc2 = make_counter_buffers(tables.policy)
-    telem = make_telemetry_buffers()
-    oi2, oe2, acc2, telem = datapath_step_accum_pair_telem(
-        tables, fin, feg, acc2, telem
-    )
+    (oi1, oe1), acc1 = _accum_in_sequence(tables, f_in, f_eg)
+    oi2, oe2, acc2, telem = _pair_program(tables, f_in, f_eg)
     assert (np.asarray(acc1) == np.asarray(acc2)).all()
     for a, b in ((oi1, oi2), (oe1, oe2)):
         assert (np.asarray(a.allowed) == np.asarray(b.allowed)).all()

@@ -1,13 +1,13 @@
 """Verdict-memoization profile: the Zipf hit-rate curve + dedup
-accounting of the two-level memo plane (engine/memo.py) over the
-bench's config-5 world at reduced control-plane scale.
+accounting of the two-level memo plane (engine/memo.py) over
+bench.py's config-5 world at reduced control-plane scale.
 
 For each skew s the tool replays Zipf(s)-sampled pool flows through
-the memoized fused pair program (the bench's headline shape with the
+the memoized fused pair program (the fused-pair shape with the
 memo plane in front) and reports the steady-state cache hit rate,
 the intra-batch dedup factor, and the EFFECTIVE hot bytes gathered
-per tuple — gatherprof's bytes-moved model divided by the measured
-dedup factor — next to the raw number.  Asserts:
+per tuple — autotune.hot_bytes_per_tuple's bytes-moved model divided
+by the measured dedup factor — next to the raw number.  Asserts:
 
   * dedup_factor >= 2 at s=1.1 (the trace-skew shape the dedup level
     exists for must actually collapse the lattice work);
@@ -15,7 +15,7 @@ dedup factor — next to the raw number.  Asserts:
     added -> delta-scoped regenerate -> fresh epoch stamp): the
     epoch-stamped invalidation can never serve a stale verdict;
   * every memoized batch is bit-identical to the uncached program on
-    the allowed column (the full-surface gate lives in bench.py and
+    the allowed column (the full-surface gate lives in
     tests/test_verdict_memo.py; this smoke keeps one cheap check).
 
 Hit-rate ABSOLUTES here describe the sampled distribution, not

@@ -17,7 +17,7 @@ a unix socket, POSTs a flow-record buffer with a caller-supplied
     span durations per phase (StatSpan shares one clock window);
   * a dispatch fault produces an `engine.hostpath` failover span in
     the trace (degraded batches are attributed, not invisible);
-  * tracer bookkeeping stays under the bench gate
+  * tracer bookkeeping stays under its gate
     (tracing_overhead_pct < 3% measured over warmed batches).
 
 Runs in tier-1 (tests/test_trace_smoke.py, not slow) and standalone:
@@ -275,7 +275,7 @@ def main() -> int:
         overhead_pct = overhead / max(wall - overhead, 1e-9) * 100.0
         assert overhead_pct < 3.0, (
             f"tracing overhead {overhead_pct:.3f}% breaches the "
-            f"bench gate"
+            f"3% gate"
         )
         print(
             json.dumps(
