@@ -153,6 +153,12 @@ def compile_fleet_l7(daemon) -> FleetL7:
         if http_specs
         else None
     )
+    if http is not None:
+        for f in ("method", "path", "host"):
+            sdfa = getattr(http.tables, f + "_sdfa")
+            metrics.policy_l7_dfa_stride.set(
+                f, value=1 if sdfa is None else sdfa.stride
+            )
     kafka = (
         compile_kafka_rules(kafka_specs, n_identities)
         if kafka_specs

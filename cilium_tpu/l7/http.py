@@ -337,32 +337,34 @@ class StridedDFA:
     The sequential byte-at-a-time scan is the HTTP path's cost center;
     squaring the transition table — with column deduplication between
     rounds and an artificial identity class so padding can never move
-    the state — divides the step count by the stride.  The union DFAs
-    here are tiny (tens of states), so the tables stay kilobytes.
+    the state — divides the step count by the stride.  The fleet's
+    union DFAs run from tens of states (method) to 1-2k (path, Host);
+    build_strided keeps every level's deduplicated table under its
+    byte budget (an API-gateway fleet's 1-2k-state path and Host
+    unions: 0.66-0.70 MB at stride 2).
 
     Level map l takes a pair of level-(l-1) classes to a level-l
     class.  The device evaluation maps byte values to level-0 classes
     and folds pairs level by level BEFORE the scan — and because every
-    lookup's table is small, the folds run as one-hot × table matmuls
-    on the MXU (measured 5-35× faster than XLA's gather lowering for
-    K ≤ ~2k on v5e; gathers cost ~5-9 ns/element, the systolic array
-    ~0.4 ns) — then scans the remaining positions with the transition
-    table OF THAT LEVEL (level_trans[d], retained per round)."""
+    level's pair count is at most MXU_LOOKUP_MAX_K, the folds run as
+    one-hot × table matmuls on the MXU (measured 5-35× faster than
+    XLA's gather lowering for K ≤ ~2k on v5e; gathers cost ~5-9
+    ns/element, the systolic array ~0.4 ns) — then scans the remaining
+    positions with the last level's transition table."""
 
     classes: np.ndarray  # byte → level-0 class (identity class added)
     id_class0: int
-    base_trans: np.ndarray  # [S, nc0] incl identity column (level 0)
     level_maps: List[np.ndarray]  # [nc_prev * nc_prev] → class id
     level_ncs: List[int]  # nc INPUT of each level
     level_ids: List[int]  # identity class id at each level OUTPUT
-    # transition table AFTER each level (level_trans[k] pairs with a
-    # class sequence folded through level_maps[:k+1]); the MXU scan
-    # picks its fold depth by table size, so every depth's table is
-    # kept (they are kilobytes)
-    level_trans: List[np.ndarray]
-    trans: np.ndarray  # [S, nc_final] == level_trans[-1]
+    trans: np.ndarray  # [S, nc_final] int32, after the last level
     start: int
     accept: np.ndarray
+
+    @property
+    def stride(self) -> int:
+        """Bytes one scan step consumes."""
+        return 1 << len(self.level_maps)
 
 
 # one-hot×table matmul beats XLA's gather lowering up to roughly this
@@ -373,54 +375,60 @@ MXU_LOOKUP_MAX_K = 2048
 def build_strided(
     dfa: DFA, rounds: int = 4, max_table_bytes: int = 1 << 22
 ) -> "Optional[StridedDFA]":
-    """Square the transition table `rounds` times (stride 2^rounds),
-    deduping equivalent columns between rounds and carrying an
-    identity class for padding."""
-    trans = dfa.trans.astype(np.int64)
+    """Square the transition table up to `rounds` times (stride up to
+    2^rounds), deduping equivalent columns between rounds and carrying
+    an identity class for padding.
+
+    A level is built only where the scan can fold into it and the
+    device can hold it: its input pair count nc² is at most
+    MXU_LOOKUP_MAX_K (the scan folds pairs on the MXU and stops at the
+    first level above that), and its deduplicated table [S, n_unique]
+    int32 — the array the scan uploads — is at most `max_table_bytes`.
+    The first rule also bounds the squaring's host transient to
+    S × MXU_LOOKUP_MAX_K × 4 bytes.
+
+    A DFA whose very first pair fold exceeds MXU_LOOKUP_MAX_K (more
+    than 45 byte classes with the identity class) gets no strided form:
+    None, and callers scan it byte by byte with `_dfa_scan`."""
+    trans = dfa.trans.astype(np.int32)  # states < 2^31: squaring exact
     s_count, nc = trans.shape
     # identity column: padding bytes leave the state unchanged, so a
     # stride group that crosses the end of the string is exact
     trans = np.concatenate(
-        [trans, np.arange(s_count, dtype=np.int64)[:, None]], axis=1
+        [trans, np.arange(s_count, dtype=np.int32)[:, None]], axis=1
     )
     id_class = nc
     nc += 1
-    base_trans = trans.astype(np.int32)
 
     level_maps: List[np.ndarray] = []
     level_ncs: List[int] = []
     level_ids: List[int] = []
-    level_trans: List[np.ndarray] = []
     cur_id = id_class
     for _ in range(rounds):
-        if s_count * nc * nc * 8 > max_table_bytes:
+        if nc * nc > MXU_LOOKUP_MAX_K:
             break
-        # T2[s, c1, c2] = trans[trans[s, c1], c2]
         t2 = trans[trans, :]  # t2[s, c1, c2] = trans[trans[s, c1], c2]
         flat = t2.reshape(s_count, nc * nc)
         cols, inverse = np.unique(flat.T, axis=0, return_inverse=True)
+        if cols.nbytes > max_table_bytes:
+            break
         level_maps.append(inverse.astype(np.int32))
         level_ncs.append(nc)
-        trans = cols.T.astype(np.int64)  # [S, n_unique]
+        trans = np.ascontiguousarray(cols.T)  # [S, n_unique]
         cur_id = int(inverse[cur_id * nc + cur_id])
         level_ids.append(cur_id)
-        level_trans.append(trans.astype(np.int32))
         nc = trans.shape[1]
 
     if not level_maps:
-        # squaring never fit the budget: no strided form — callers
-        # use the byte-at-a-time scan
         return None
 
     return StridedDFA(
         classes=dfa.classes.astype(np.int32),
         id_class0=id_class,
-        base_trans=base_trans,
         level_maps=level_maps,
         level_ncs=level_ncs,
         level_ids=level_ids,
-        level_trans=level_trans,
-        trans=level_trans[-1],
+        trans=trans,
         start=dfa.start,
         accept=dfa.accept,
     )
@@ -459,12 +467,12 @@ def _mxu_lookup(idx, table: np.ndarray):
 def _dfa_scan_strided(sdfa: StridedDFA, data, lengths):
     """[B, L] u8 → accept bitmask.  Positions past the string length
     become the identity class before the level folding, so padding is
-    state-neutral by construction.  Byte-classing and the small-table
-    pair folds run on the MXU (_mxu_lookup); folding stops at the
-    first level whose pair table exceeds MXU_LOOKUP_MAX_K, and the
-    remaining positions scan sequentially with that level's
-    transition table (scan-step gathers are the one gather shape that
-    stays cheap: [B] elements per step)."""
+    state-neutral by construction.  Byte-classing and the pair folds of
+    every level run on the MXU (_mxu_lookup; build_strided builds no
+    level whose pair table exceeds MXU_LOOKUP_MAX_K), and the remaining
+    positions scan sequentially with the last level's transition table
+    (scan-step gathers are the one gather shape that stays cheap: [B]
+    elements per step)."""
     import jax
     import jax.numpy as jnp
 
@@ -482,12 +490,9 @@ def _dfa_scan_strided(sdfa: StridedDFA, data, lengths):
     c = _mxu_lookup(p, classes_e)  # [B, L]
     pad_id = sdfa.id_class0
 
-    depth = -1
-    for k, (pair_map, nc_in, out_id) in enumerate(
-        zip(sdfa.level_maps, sdfa.level_ncs, sdfa.level_ids)
+    for pair_map, nc_in, out_id in zip(
+        sdfa.level_maps, sdfa.level_ncs, sdfa.level_ids
     ):
-        if nc_in * nc_in > MXU_LOOKUP_MAX_K:
-            break
         if c.shape[1] % 2:
             c = jnp.concatenate(
                 [c, jnp.full((b, 1), pad_id, jnp.int32)], axis=1
@@ -496,14 +501,8 @@ def _dfa_scan_strided(sdfa: StridedDFA, data, lengths):
             c[:, 0::2] * nc_in + c[:, 1::2], pair_map
         )  # [B, L/2]
         pad_id = out_id
-        depth = k
 
-    # scan with the transition table of the deepest folded level
-    # (base table when even the first pair map exceeded the budget —
-    # a pathological byte-class count; the scan is then per-byte)
-    trans = jnp.asarray(
-        sdfa.base_trans if depth < 0 else sdfa.level_trans[depth]
-    )
+    trans = jnp.asarray(sdfa.trans)
     nc_final = trans.shape[1]
     flat = trans.reshape(-1)
     state0 = jnp.full((b,), sdfa.start, dtype=jnp.int32)

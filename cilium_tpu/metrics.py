@@ -243,6 +243,13 @@ class Registry:
             "persistent launch path, by parser",
             ("parser",),  # http|kafka
         )
+        self.policy_l7_dfa_stride = Gauge(
+            f"{ns}_policy_l7_dfa_stride",
+            "Bytes each scan step of the fleet HTTP matcher's union "
+            "DFA consumes, by field (1 = the byte-at-a-time scan), "
+            "set when the fleet L7 policy compiles",
+            ("field",),  # method|path|host
+        )
         self.drop_count = Counter(
             f"{ns}_drop_count_total",
             "Total dropped packets by reason and direction",

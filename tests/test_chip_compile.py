@@ -194,3 +194,29 @@ def test_http_strided_dfa_scan_compiles_for_v5e(one_chip):
         s((B, 16), jnp.uint8), lens, s((B, 128), jnp.uint8), lens,
         s((B, 64), jnp.uint8), lens, lens, s((B,), jnp.bool_),
     )
+
+
+def test_gateway_http_matcher_compiles_for_v5e(one_chip):
+    """The API-gateway fleet's HTTP matcher at one L7 step's chunk,
+    32-byte path and Host: both unions take the strided scan."""
+    from cilium_tpu.l7.fleet import L7_CHUNK
+    from cilium_tpu.l7.http import compile_http_rules, evaluate_http_batch
+    from tests.test_l7_http import gateway_rules
+
+    tables = compile_http_rules(gateway_rules(), n_identities=16).tables
+    assert tables.path_sdfa is not None and tables.path_sdfa.stride == 2
+    assert tables.host_sdfa is not None and tables.host_sdfa.stride == 2
+
+    def scan(m, ml, p, pl, h, hl, idx, known):
+        return evaluate_http_batch(tables, m, ml, p, pl, h, hl, idx, known)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lens = s((L7_CHUNK,), jnp.int32)
+    _compile(
+        jax.jit(scan),
+        s((L7_CHUNK, 16), jnp.uint8), lens, s((L7_CHUNK, 32), jnp.uint8),
+        lens, s((L7_CHUNK, 32), jnp.uint8), lens, lens,
+        s((L7_CHUNK,), jnp.bool_),
+    )

@@ -111,6 +111,13 @@ def test_fleet_l7_scope_isolation():
 
     fleet = compile_fleet_l7(d)
     assert fleet.http is not None and fleet.kafka is not None
+    # the stride gauge reads each field's bytes per scan step
+    from cilium_tpu.metrics import registry as metrics
+
+    for f in ("method", "path", "host"):
+        sdfa = getattr(fleet.http.tables, f + "_sdfa")
+        assert sdfa is not None and sdfa.stride > 1
+        assert metrics.policy_l7_dfa_stride.get(f) == sdfa.stride
 
     _, tables, ep_index = d.endpoint_manager.published()
     id_index, _ = d.endpoint_manager.identity_index()

@@ -359,3 +359,208 @@ def test_mxu_lookup_matches_numpy_gather():
             lambda i, t=table: _mxu_lookup(i, t)
         )(idx))
         np.testing.assert_array_equal(got, table[idx])
+
+
+# ---------------------------------------------------------------------------
+# strided scan: stride chosen by the table the device holds
+# ---------------------------------------------------------------------------
+
+# an API-gateway node's HTTP fleet: 128 route templates and 64 service
+# Hosts, each union with the never-matching pattern an absent field
+# compiles to (compile_http_rules' union_for)
+GW_RESOURCES = (
+    "users", "orders", "items", "carts", "payments", "invoices",
+    "accounts", "sessions", "products", "reviews", "tickets", "messages",
+    "events", "metrics", "reports", "tokens", "groups", "roles", "teams",
+    "projects", "builds", "jobs", "files", "images", "videos", "comments",
+    "likes", "tags", "feeds", "alerts", "quotas", "plans",
+)
+GW_SUBS = ("status", "history", "items", "owner")
+GW_NAMESPACES = ("default", "payments", "orders", "identity")
+GW_ROUTES = [
+    f"/api/v[1-3]/{res}(/[0-9]+)?(/{sub})?"
+    for res in GW_RESOURCES for sub in GW_SUBS
+]
+GW_HOSTS = [
+    f"svc{k}\\.{ns}\\.svc\\.cluster\\.local"
+    for k in range(16) for ns in GW_NAMESPACES
+]
+ABSENT = "[^\\x00-\\xff]"
+
+
+def gateway_rules():
+    """HTTPRuleSpecs whose union DFAs are the gateway's: one per route
+    (the first 64 with a Host each) and an allow-all pseudo-rule."""
+    return [
+        HTTPRuleSpec(
+            identity_indices=[i % 16],
+            method=("GET", "POST", "PUT", "DELETE", "GET|HEAD")[i % 5],
+            path=route,
+            host=GW_HOSTS[i] if i < len(GW_HOSTS) else "",
+        )
+        for i, route in enumerate(GW_ROUTES)
+    ] + [HTTPRuleSpec(identity_indices=[0])]
+
+
+@pytest.fixture(scope="module")
+def gw_unions():
+    from cilium_tpu.l7.http import build_strided
+
+    out = {}
+    for name, patterns in (
+        ("path", GW_ROUTES + [ABSENT]),
+        ("host", GW_HOSTS + [ABSENT]),
+    ):
+        dfa = compile_union(patterns)
+        out[name] = (patterns, dfa, build_strided(dfa))
+    return out
+
+
+def _gw_request(rng, name) -> bytes:
+    """A value for the gateway's `name` field: a match, a near miss
+    (version 4, a trailing 'x', the next namespace, svc16) or noise."""
+    u = rng.random()
+    if name == "path":
+        res = GW_RESOURCES[rng.integers(len(GW_RESOURCES))]
+        v = 4 if u < 0.2 else int(rng.integers(1, 4))
+        s = f"/api/v{v}/{res}"
+        if rng.random() < 0.5:
+            s += f"/{int(rng.integers(1, 10**int(rng.integers(1, 8))))}"
+        if rng.random() < 0.5:
+            s += f"/{GW_SUBS[rng.integers(len(GW_SUBS))]}"
+    else:
+        k = 16 if u < 0.15 else int(rng.integers(16))
+        ns = int(rng.integers(len(GW_NAMESPACES)))
+        if 0.15 <= u < 0.3:
+            ns = (ns + 1) % len(GW_NAMESPACES) + len(GW_NAMESPACES)
+        ns_name = (GW_NAMESPACES * 2 + ("kube-system",))[ns]
+        s = f"svc{k}.{ns_name}.svc.cluster.local"
+    if 0.3 <= u < 0.45:
+        s += "x"
+    elif 0.45 <= u < 0.5:
+        s = "".join(chr(int(c)) for c in rng.integers(32, 127, size=9))
+    return s.encode()
+
+
+def _fullmatch_bits(patterns, value: bytes, words: int):
+    bits = [0] * words
+    for i, pattern in enumerate(patterns):
+        if re.fullmatch(pattern.encode(), value, re.DOTALL):
+            bits[i // 32] |= 1 << (i % 32)
+    return bits
+
+
+@pytest.mark.parametrize("name", ["path", "host"])
+def test_gateway_union_strides_once_within_budget(gw_unions, name):
+    """The gateway's path (1,023 states) and Host (1,653) unions get
+    exactly one level: its deduplicated table fits the byte budget
+    though the squaring's pre-dedup transient does not, and the next
+    level's pair count would exceed the MXU fold's K, so it is not
+    built."""
+    from cilium_tpu.l7.http import MXU_LOOKUP_MAX_K, build_strided
+
+    _, dfa, sdfa = gw_unions[name]
+    budget = 1 << 22  # build_strided's default
+    assert sdfa is not None and sdfa.stride == 2
+    assert sdfa.trans.dtype == np.int32
+    assert sdfa.trans.shape[0] == dfa.n_states
+    assert sdfa.trans.nbytes <= budget
+    nc0 = dfa.trans.shape[1] + 1  # with the identity class
+    assert sdfa.level_ncs == [nc0]
+    assert dfa.n_states * nc0 * nc0 * 8 > budget  # the int64 transient
+    assert all(nc * nc <= MXU_LOOKUP_MAX_K for nc in sdfa.level_ncs)
+    assert sdfa.trans.shape[1] ** 2 > MXU_LOOKUP_MAX_K
+    # the budget bounds the deduplicated table: one byte under it, no
+    # level, hence no strided form
+    assert build_strided(dfa, max_table_bytes=sdfa.trans.nbytes - 1) is None
+    again = build_strided(dfa, max_table_bytes=sdfa.trans.nbytes)
+    assert again is not None and again.stride == 2
+
+
+@pytest.mark.parametrize("seed", range(2))
+@pytest.mark.parametrize("name", ["path", "host"])
+def test_gateway_strided_scan_matches_bytewise_and_re(gw_unions, name, seed):
+    """_dfa_scan_strided == _dfa_scan == re.fullmatch on the gateway's
+    union, over values of lengths 0, 1, odd, 31 and 32 (prefixes of
+    matches and near misses cut to those lengths) and whole ones."""
+    import jax
+
+    from cilium_tpu.l7.http import _dfa_scan, _dfa_scan_strided
+
+    patterns, dfa, sdfa = gw_unions[name]
+    rng = np.random.default_rng(seed)
+    width = 32
+    values = []
+    while len(values) < 192:
+        v = _gw_request(rng, name)
+        if len(v) <= width:
+            values.append(v)
+    for n in (0, 1, 7, 13, 31, 32):
+        for _ in range(8):
+            v = _gw_request(rng, name) + b"/0123456789abcdefghijklmnopqrst"
+            values.append(v[:n])
+    data = np.zeros((len(values), width), np.uint8)
+    lens = np.zeros(len(values), np.int32)
+    for i, v in enumerate(values):
+        data[i, : len(v)] = np.frombuffer(v, np.uint8)
+        lens[i] = len(v)
+    assert {0, 1, 31, 32} <= set(lens.tolist())
+
+    strided = np.asarray(jax.jit(
+        lambda d, n: _dfa_scan_strided(sdfa, d, n)
+    )(data, lens))
+    bytewise = np.asarray(jax.jit(lambda d, n: _dfa_scan(dfa, d, n))(
+        data, lens
+    ))
+    np.testing.assert_array_equal(strided, bytewise)
+    words = strided.shape[1]
+    want = np.asarray(
+        [_fullmatch_bits(patterns, v, words) for v in values], np.uint32
+    )
+    np.testing.assert_array_equal(strided, want)
+    assert want.any() and not want.all()  # matches and misses both
+
+
+def test_union_past_mxu_fold_keeps_bytewise_scan():
+    """A union with more than 45 byte classes (identity class
+    included) has a first pair fold past MXU_LOOKUP_MAX_K: it gets no
+    strided form and scans byte by byte, as before (its squared table
+    never fit the old budget either), with the same verdicts."""
+    from cilium_tpu.l7.http import MXU_LOOKUP_MAX_K, build_strided
+
+    rng = np.random.default_rng(11)
+    alphabet = "abcdefghijklmnopqrstuvwxyzABCDEFGHIJKLMNOPQRSTUVWXYZ0123"
+    words = [
+        "".join(alphabet[j] for j in rng.integers(len(alphabet), size=6))
+        for _ in range(64)
+    ]
+    rules = [
+        HTTPRuleSpec(identity_indices=[i % 4], path=f"/{w}(/[0-9]+)?")
+        for i, w in enumerate(words)
+    ]
+    policy = compile_http_rules(rules, n_identities=4)
+    dfa = policy.tables.path_dfa
+    nc0 = dfa.trans.shape[1] + 1
+    assert nc0 * nc0 > MXU_LOOKUP_MAX_K
+    assert build_strided(dfa) is None
+    assert policy.tables.path_sdfa is None
+
+    reqs, idents = [], []
+    for i in range(160):
+        w = words[i % len(words)]
+        path = (f"/{w}", f"/{w}/{i}", f"/{w}x", f"/{w[:-1]}", "/")[i % 5]
+        reqs.append((b"GET", path.encode(), b""))
+        idents.append(int(rng.integers(0, 4)))
+    m, ml, p, pl, h, hl, _ = pad_requests(reqs)
+    allowed, _ = evaluate_http_batch(
+        policy.tables, m, ml, p, pl, h, hl,
+        ident_idx=np.array(idents, dtype=np.int32),
+        known=np.ones(len(reqs), dtype=bool),
+    )
+    want = [
+        any(idx in r.identity_indices
+            and http_rule_matches_host(r, mm, pp, hh) for r in rules)
+        for (mm, pp, hh), idx in zip(reqs, idents)
+    ]
+    assert np.asarray(allowed).tolist() == want
+    assert any(want) and not all(want)
